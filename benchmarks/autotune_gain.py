@@ -109,6 +109,9 @@ def run(*, config: str = "nid_mlp", batch: int = 4096, reps: int = 5,
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--config", default="nid_mlp", choices=("nid_mlp", "cnv"))
     ap.add_argument("--batch", type=int, default=4096)

@@ -302,6 +302,9 @@ def run(*, requests: int = 160, seed: int = 0, load: float = 0.25,
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--requests", type=int, default=None)
     ap.add_argument("--load", type=float, default=0.25,

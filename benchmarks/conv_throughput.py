@@ -126,6 +126,9 @@ def run(*, batch: int = 256, reps: int = 5, seed: int = 0, mode: str = "xnor",
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--batch", type=int, default=256)
     ap.add_argument("--reps", type=int, default=5)

@@ -92,6 +92,9 @@ def run(*, batch: int = 4096, reps: int = 5, seed: int = 0,
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--batch", type=int, default=4096)
     ap.add_argument("--reps", type=int, default=5)

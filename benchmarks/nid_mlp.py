@@ -39,7 +39,7 @@ def run(out=None):
         a_s = jax.ShapeDtypeStruct((128, k), jnp.int8)
         w_s = jax.ShapeDtypeStruct((n, k), jnp.int8)
         hls = compile_probe(hls_ref_fn("standard", k), a_s, w_s)
-        blocks = to_tpu_blocks(fold, "standard")
+        blocks = to_tpu_blocks(fold, "standard", n=n, k=k)
         rtl = compile_probe(rtl_kernel_fn("standard", k, blocks), a_s, w_s)
         rows.append({
             "layer": i, "K": k, "N": n, "PE": pe, "SIMD": simd,

@@ -84,4 +84,7 @@ def run_quick(out_dir: str | None = None, *, batch: int = 512,
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     print(run_quick(out_dir="experiments/bench"))

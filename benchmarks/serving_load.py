@@ -260,6 +260,9 @@ def run(*, requests: int = 1024, rounds: int = 3, rate_hz: float | None = None,
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--requests", type=int, default=1024)
     ap.add_argument("--rounds", type=int, default=3,

@@ -55,7 +55,7 @@ def run_chain(lengths=(1, 2, 4, 8, 16, 32), n=64) -> list[dict]:
         t0 = time.perf_counter()
         key = ("standard", 32, 32)
         if key not in rtl_cache:
-            blocks = to_tpu_blocks(Folding(32, 32), "standard")
+            blocks = to_tpu_blocks(Folding(32, 32), "standard", n=n, k=n)
             rtl_cache[key] = compile_probe(
                 rtl_kernel_fn("standard", n, blocks),
                 jax.ShapeDtypeStruct((128, n), jnp.int8),
@@ -76,7 +76,7 @@ def run_folding(values=(2, 8, 32, 64), n=64, k=1024) -> list[dict]:
     parameterization (compiled) but the same HLS reference shape."""
     rows = []
     for v in values:
-        blocks = to_tpu_blocks(Folding(v, 64), "standard")
+        blocks = to_tpu_blocks(Folding(v, 64), "standard", n=n, k=k)
         a_s = jax.ShapeDtypeStruct((128, k), jnp.int8)
         w_s = jax.ShapeDtypeStruct((n, k), jnp.int8)
         hls = compile_probe(lambda a, w: ref.mvu_int_ref(a, w), a_s, w_s)

@@ -116,6 +116,9 @@ def run(*, requests: int = 512, rounds: int = 5, seed: int = 0,
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--requests", type=int, default=512)
     ap.add_argument("--rounds", type=int, default=5,

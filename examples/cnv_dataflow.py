@@ -20,6 +20,9 @@ from repro.configs import cnv_bnn
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     spec = cnv_bnn.QUICK  # 1/8-channel CNV on 16x16 inputs; FULL = the real one
     acc = build(
         cnv_bnn.build_graph(spec, seed=0),

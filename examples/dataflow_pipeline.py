@@ -19,9 +19,13 @@ from repro.distributed.pipeline import (
     sequential_reference,
     stage_params_split,
 )
+from repro.launch.mesh import make_mesh
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     n_dev = len(jax.devices())
     stages = 4 if n_dev >= 4 else n_dev
     L, d = 8, 64
@@ -45,7 +49,7 @@ def main():
     print(f"[dataflow] steady-state interval = {max(cycles)} cycles, "
           f"fill/drain bubbles = {stages - 1} microbatch ticks")
 
-    mesh = jax.make_mesh((stages,), ("stage",))
+    mesh = make_mesh((stages,), ("stage",))
     out = pipeline_apply(layer_fn, stage_params_split(params, stages), x, mesh)
     want = sequential_reference(layer_fn, params, x)
     err = float(jnp.abs(out - want).max())

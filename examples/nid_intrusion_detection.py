@@ -30,6 +30,9 @@ from repro.core.resource_model import mvu_resources
 
 
 def main(fast: bool = False):
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     print("== NID MLP (paper Table 6): 600-64-64-64-1 @ 2-bit ==")
     for i, (k, n, pe, simd) in enumerate(nid_mlp.LAYERS):
         fold = Folding(pe, simd)

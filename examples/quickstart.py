@@ -20,6 +20,9 @@ from repro.kernels import ops, packing
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     key = jax.random.PRNGKey(0)
     m, n, k = 64, 64, 256
 

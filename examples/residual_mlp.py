@@ -39,6 +39,9 @@ from repro.core import ir
 
 
 def main(fast: bool = False):
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     batch = 64 if fast else 256
     graph = residual_mlp.build_graph()
     print("== residual NID-MLP variant: 600-64-(64+skip)-1 @ 2-bit ==")

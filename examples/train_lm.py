@@ -14,6 +14,9 @@ import jax
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--batch", type=int, default=16)
@@ -27,7 +30,7 @@ def main():
     n_dev = len(jax.devices())
     from repro.configs import get_config
     from repro.data.pipeline import SyntheticLM
-    from repro.launch.mesh import make_host_mesh
+    from repro.launch.mesh import make_mesh
     from repro.launch.train import train_loop
     from repro.models.model import build
     from repro.optim import adamw
@@ -46,7 +49,7 @@ def main():
           f"{n_dev} devices, quant={args.quant}")
 
     shape = {1: (1, 1), 2: (2, 1), 4: (2, 2), 8: (4, 2)}.get(n_dev, (n_dev, 1))
-    mesh = make_host_mesh(shape)
+    mesh = make_mesh(shape)
     data = SyntheticLM(cfg.vocab_size, args.seq, args.batch)
     t0 = time.time()
     _, _, hist = train_loop(

@@ -1,9 +1,1 @@
 """repro: FINN MVU reproduction on JAX/Pallas."""
-
-import jax
-
-# Sharding-invariant RNG: without this, jit(init, out_shardings=...) draws
-# different parameters than eager init for tensors partitioned on a non-last
-# axis (old threefry splits its counter per shard).  Partitionable threefry
-# is the future jax default; opt in so sharded and single-device runs agree.
-jax.config.update("jax_threefry_partitionable", True)

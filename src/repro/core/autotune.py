@@ -42,14 +42,20 @@ import jax
 import numpy as np
 
 from repro.core import ir
-from repro.core.folding import Folding, block_candidates, divisors
+from repro.core.folding import (
+    Folding,
+    block_candidates,
+    divisors,
+    legal_blocks,
+    steps_in_words,
+)
 from repro.core.ir import Graph, Node
 from repro.core.mvu import KernelBlocks, MVUConfig
 from repro.core.resource_model import VMEM_BYTES, mvu_resources
 from repro.core.swu import out_dim
 from repro.kernels import ops, packing
 from repro.kernels.packing import WORD_BITS
-from repro.kernels.swu_mvu import conv_rows_per_tile, conv_vmem_bytes
+from repro.kernels.swu_mvu import conv_vmem_bytes, fit_conv_blocks
 
 CACHE_VERSION = 1
 # user-side persistent cache (the committed defaults ship in repro.configs)
@@ -59,12 +65,9 @@ CACHE_PATH_ENV = "REPRO_AUTOTUNE_CACHE"
 
 # --------------------------------------------------------------------- keys
 def device_kind() -> str:
-    """Stable schedule-cache device key, e.g. ``cpu`` or ``tpu-v5e``."""
-    try:
-        kind = jax.devices()[0].device_kind
-    except Exception:  # pragma: no cover - no devices at all
-        kind = jax.default_backend()
-    return str(kind).strip().lower().replace(" ", "-")
+    """Stable schedule-cache device key from the first device's kind, e.g.
+    ``cpu`` or ``tpu-v5-lite`` (a v5e reports ``TPU v5 lite``)."""
+    return str(jax.devices()[0].device_kind).strip().lower().replace(" ", "-")
 
 
 def epilogue_form(params) -> str:
@@ -219,20 +222,23 @@ class ScheduleCache:
 
 
 def default_cache() -> ScheduleCache:
-    """Committed tuned defaults (configs) + the local persistent cache.
+    """Committed tuned defaults (configs) + an opt-in user cache.
 
     The per-config ``TUNED_SCHEDULES`` dicts ship in the package (zero I/O,
-    zero measurement to consume); a user cache file -- ``$REPRO_AUTOTUNE_CACHE``
-    or ``experiments/autotune/cache.json`` -- overrides them when present.
+    zero measurement to consume).  A user cache file overrides them only
+    when ``$REPRO_AUTOTUNE_CACHE`` names one, so a default run reads
+    nothing but committed files; the named path is also where the cache
+    saves.
     """
     cache = ScheduleCache()
     from repro.configs import cnv_bnn, nid_mlp
 
     for mod in (nid_mlp, cnv_bnn):
         cache.merge(ScheduleCache(getattr(mod, "TUNED_SCHEDULES", {})))
-    path = os.environ.get(CACHE_PATH_ENV, DEFAULT_CACHE_PATH)
-    if os.path.exists(path):
-        cache.merge(ScheduleCache.load(path))
+    path = os.environ.get(CACHE_PATH_ENV)
+    if path:
+        if os.path.exists(path):
+            cache.merge(ScheduleCache.load(path))
         cache.path = path
     return cache
 
@@ -261,7 +267,7 @@ class Candidate:
 def _blocks_folding(blocks: KernelBlocks, mode: str,
                     packed: bool = False) -> Folding:
     """The folding a block schedule *acts* as (PE=block_n, SIMD=K step)."""
-    if mode == "xnor" or (packed and mode == "binary"):
+    if steps_in_words(mode, packed):
         simd = blocks.block_kw * WORD_BITS
     else:
         simd = blocks.block_k
@@ -311,20 +317,23 @@ def enumerate_candidates(
     if conv is not None:
         # fused conv kernel: full-K dot per step; the schedule is block_n x
         # rows_per_tile (block_m only acts through the derived row tile, so
-        # it is pinned explicitly on the candidate)
+        # it is pinned explicitly on the candidate).  Both are rounded to
+        # the chip's layout, so equal tiles appear once.
         h, w, c = in_shape
         oh = out_dim(h, conv["kernel"], conv["stride"], conv["pad"])
         ow = out_dim(w, conv["kernel"], conv["stride"], conv["pad"])
+        seen: set[tuple[int, int]] = set()
         for bm in (32, 128, 256):
-            for bn in sorted({max(8, d) for d in divisors(n)} | {128}):
-                if bn > 512:
+            for want_bn in sorted(set(divisors(n) + [128])):
+                rt, bn = fit_conv_blocks(oh, ow, n, block_m=bm,
+                                         block_n=want_bn).values()
+                if bn > 512 or (rt, bn) in seen:
                     continue
+                seen.add((rt, bn))
                 vm = conv_vmem_bytes(
                     h, w, c, n, k, kernel=conv["kernel"], stride=conv["stride"],
                     pad=conv["pad"], block_m=bm, block_n=bn, n_thresh=n_thresh)
-                blocks = KernelBlocks(
-                    block_m=bm, block_n=bn,
-                    rows_per_tile=conv_rows_per_tile(oh, ow, bm))
+                blocks = KernelBlocks(block_m=bm, block_n=bn, rows_per_tile=rt)
                 cyc = Folding(bn, k).cycles(n, k, n_pixels)
                 cands.append(Candidate("pallas", blocks, cyc, vm))
     else:
@@ -414,7 +423,9 @@ def _synth_activations(cfg: MVUConfig, m: int, in_shape: tuple | None,
         # must be measured in that regime, not on a large batch
         h, w, c = in_shape
         lo, hi = (0, 2) if cfg.mode == "xnor" else (0, 2**cfg.act_bits)
-        return jnp.asarray(rng.integers(lo, hi, (1, h, w, c)), jnp.int32)
+        # int8 where it fits, as the engine narrows such streams
+        return jnp.asarray(rng.integers(lo, hi, (1, h, w, c)),
+                           jnp.int8 if hi <= 128 else jnp.int32)
     k = cfg.in_features
     if cfg.mode == "xnor":
         bits = jnp.asarray(rng.integers(0, 2, (m, k)), jnp.int32)
@@ -516,10 +527,14 @@ def tune_node(
         in ignored fields (conv ignores the K blocks, block_m acts through
         rows_per_tile) must not be timed against each other."""
         if conv is not None:
-            rt = c.blocks.rows_per_tile or conv_rows_per_tile(
-                oh, ow, c.blocks.block_m)
-            return (c.backend, c.blocks.block_n, rt)
-        kw = c.blocks.as_kwargs(cfg.mode, c.packed)
+            rt, bn = fit_conv_blocks(
+                oh, ow, cfg.out_features, block_m=c.blocks.block_m,
+                block_n=c.blocks.block_n,
+                rows_per_tile=c.blocks.rows_per_tile).values()
+            return (c.backend, bn, rt)
+        kw = legal_blocks(c.blocks.as_kwargs(cfg.mode, c.packed),
+                          cfg.out_features, cfg.in_features, cfg.mode,
+                          packed=c.packed)
         kw.pop("rows_per_tile", None)
         # packed xnor runs the same Pallas kernel but a different XLA path,
         # so the storage axis is part of the effective identity throughout

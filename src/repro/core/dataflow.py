@@ -179,7 +179,59 @@ def schedule(graph: Graph) -> DataflowSchedule:
                             critical_path_cycles=max(lat.values(), default=0))
 
 
-def node_runner(node):
+def _int_range(node, ins: list) -> tuple[int, int] | None:
+    """Static integer range of one node's output, given its inputs' ranges
+    (None = not a bounded integer stream)."""
+    if node.op == "input":
+        bits = node.attrs.get("bits")
+        # either sign: the feed may be signed or unsigned on ``bits`` bits
+        return None if bits is None else (1 - 2**bits, 2**bits - 1)
+    if node.op == "quant_act":
+        return (0, 2 ** node.attrs["bits"] - 1)
+    if node.op in ("swu", "maxpool", "flatten"):
+        return ins[0]
+    if node.op in ("mvu", "conv_mvu"):
+        p = node.params.get("mvu")
+        if p is None or p.thresholds is None:
+            return None  # raw accumulator or float scale
+        return (0, int(p.thresholds.shape[-1]))
+    if node.op in ir.ELTWISE_OPS and None not in ins:
+        scales = node.attrs.get("scales", (1, 1))
+        if not all(isinstance(s, int) for s in scales):
+            return None
+        (alo, ahi), (blo, bhi) = (sorted((lo * s, hi * s))
+                                  for (lo, hi), s in zip(ins, scales))
+        if node.op == "add":
+            return (alo + blo, ahi + bhi)
+        if node.op == "sub":
+            return (alo - bhi, ahi - blo)
+        prods = (alo * blo, alo * bhi, ahi * blo, ahi * bhi)
+        return (min(prods), max(prods))
+    return None
+
+
+def int8_inputs(graph) -> frozenset[str]:
+    """The mvu/conv_mvu nodes whose input stream provably fits int8.
+
+    Static ranges: an input node's ``bits`` (either sign), a quant_act's
+    grid, a thresholded MVU's levels [0, T], carried through
+    swu/maxpool/flatten and combined by the eltwise ops with integer
+    scales; anything else is unknown.  :func:`node_runner` narrows these
+    streams to int8, so the MXU kernels run one pass; any other stream is
+    computed exactly in base-256 digits (``kernels._common.by_int8_digits``).
+    """
+    ranges: dict[str, tuple[int, int] | None] = {}
+    out = set()
+    for node in ir.toposort(graph):
+        ins = [ranges.get(s) for s in node.inputs]
+        if (node.op in ("mvu", "conv_mvu") and ins[0] is not None
+                and -128 <= ins[0][0] and ins[0][1] <= 127):
+            out.add(node.name)
+        ranges[node.name] = _int_range(node, ins)
+    return frozenset(out)
+
+
+def node_runner(node, *, int8_input: bool = False):
     """Per-node semantics as ``(params, fn)`` with ``fn(params, *xs) -> x``.
 
     The eager interpreter (:func:`execute`) and the fused engine
@@ -187,6 +239,8 @@ def node_runner(node):
     so the jit-compiled engine is bit-exact with the behavioural model by
     construction.  ``params`` is the node's traced pytree (or ``None``).
     Single-input ops take one array; elementwise-binary ops take two.
+    ``int8_input`` (from :func:`int8_inputs`) carries an MVU's input in
+    int8, which the range proof makes lossless.
     """
     if node.op == "input":
         return None, lambda p, x: x
@@ -223,6 +277,8 @@ def node_runner(node):
 
         def run_conv(p, x):
             b, h, w, _ = x.shape
+            if int8_input:
+                x = x.astype(jnp.int8)
             out = ops.conv_mvu(
                 x, p.weights,
                 kernel=kd, stride=st, pad=pd, mode=cfg.mode,
@@ -257,6 +313,8 @@ def node_runner(node):
         def run_mvu(p, x):
             if cfg.mode == "xnor" and x.dtype != jnp.uint32:
                 x = packing.pack_bits(x.astype(jnp.int32))
+            elif int8_input:
+                x = x.astype(jnp.int8)
             return layer(p, x)
 
         return node.params["mvu"], run_mvu
@@ -297,8 +355,9 @@ def trace(graph: Graph, x) -> dict[str, jax.Array]:
                 "{name: array} dict instead of one array")
         feeds = {heads[0].name: x}
     env: dict[str, jax.Array] = {}
+    narrow = int8_inputs(order)
     for node in order:
-        params, fn = node_runner(node)
+        params, fn = node_runner(node, int8_input=node.name in narrow)
         if node.op == "input":
             if node.name not in feeds:
                 raise ValueError(f"no feed for input node {node.name!r}")

@@ -110,7 +110,9 @@ class FusedEngine:
         # stage order is the dataflow (topological) order -- identical to
         # list order for chains, and the streaming order for branched graphs
         order = ir.toposort(self.graph)
-        runners = [dataflow.node_runner(n) for n in order]
+        narrow = dataflow.int8_inputs(order)
+        runners = [dataflow.node_runner(n, int8_input=n.name in narrow)
+                   for n in order]
         self._fns = tuple(fn for _, fn in runners)
         self.params = [p for p, _ in runners]
         self._names = tuple(n.name for n in order)
@@ -303,8 +305,11 @@ class FusedEngine:
             stacked["t"] = jnp.stack(thr)
         if scl[0] is not None:
             stacked["s"] = jnp.stack(scl)
+        # one stage fn runs every layer: narrow only if every input fits
+        narrow = {n.name for n in non_input} <= dataflow.int8_inputs(self.graph)
         layer_fn = kops.mvu_layer_fn(
-            cfgs[0].mode, backend=cfgs[0].backend, **cfgs[0].kernel_blocks()
+            cfgs[0].mode, backend=cfgs[0].backend, int8_input=narrow,
+            **cfgs[0].kernel_blocks()
         )
         n_stages = mesh.shape[axis]
         stage_params = stage_params_split(stacked, n_stages)

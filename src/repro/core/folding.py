@@ -9,8 +9,10 @@ onto a PE x SIMD array:
     total cycles = n_pixels * NF * SF
 
 On TPU, PE maps to the kernel's block_n and SIMD to block_k (x32 synapses
-per packed word for the XNOR datapath), so "folding" becomes BlockSpec tile
-selection under a VMEM budget -- same math, same balance condition.
+per packed word for the XNOR datapath), rounded to the chip's layout, so
+"folding" becomes BlockSpec tile selection under a VMEM budget.  The cycle
+model (``Folding.cycles``) and the balance condition stay on the FINN
+(PE, SIMD) values; only the tiles the kernels run are rounded.
 
 The pipeline balancer reproduces FINN's *Folding and Resource Estimation*
 pass: given a cycle target, assign each layer the smallest PE*SIMD product
@@ -24,7 +26,8 @@ import dataclasses
 import math
 from typing import Sequence
 
-from repro.kernels.packing import WORD_BITS
+from repro.kernels import mvu_int, mvu_packed, mvu_xnor
+from repro.kernels.packing import WORD_BITS, num_words
 
 
 @dataclasses.dataclass(frozen=True)
@@ -122,25 +125,49 @@ def balance_pipeline(
     ]
 
 
-def to_tpu_blocks(fold: Folding, mode: str, m: int = 128, *,
+def steps_in_words(mode: str, packed: bool = False) -> bool:
+    """Whether the datapath steps K in 32-bit packed words (block_kw)."""
+    return mode == "xnor" or (packed and mode == "binary")
+
+
+def legal_blocks(blocks: dict, n: int, k: int, mode: str, *,
+                 packed: bool = False) -> dict[str, int]:
+    """The tile the kernels run for a requested (N, K) schedule.
+
+    Asks the kernel that runs ``mode`` for its own rounding (each kernel
+    module's ``fit_blocks``): block_n to 128 lanes or the whole N, the K
+    step to 128 lanes (of int8 synapses, packed words or 2-bit lane bytes)
+    or the whole K, block_m to 8 sublanes.  Keys other than the block
+    edges pass through unchanged.
+    """
+    if steps_in_words(mode, packed):
+        fit, kkey = mvu_xnor.fit_blocks, "block_kw"
+    elif packed:
+        fit, kkey = mvu_packed.fit_int2_blocks, "block_k"
+    else:
+        fit, kkey = mvu_int.fit_blocks, "block_k"
+    return {**blocks, **fit(n, k, block_m=blocks["block_m"],
+                            block_n=blocks["block_n"], **{kkey: blocks[kkey]})}
+
+
+def to_tpu_blocks(fold: Folding, mode: str, m: int = 128, *, n: int, k: int,
                   packed: bool = False) -> dict[str, int]:
-    """Map (PE, SIMD) onto Pallas block shapes.
+    """Map (PE, SIMD) onto the Pallas block shapes the kernels run.
 
     block_n = PE (output rows in parallel), block_k = SIMD synapses per grid
     step; the XNOR datapath packs 32 synapses per word so block_kw =
     SIMD / 32, and the packed binary datapath steps the same word axis.
-    Packed 2-bit weights carry 4 lanes per byte, so block_k rounds up to a
-    whole number of bytes.  Values are clamped up to TPU-friendly minima
-    (8 sublanes / 128 lanes) -- small FPGA-style arrays are legal but pad
-    on real silicon.
+    Each edge is then rounded to the chip's layout by
+    :func:`legal_blocks`: a multiple of 128 lanes (8 sublanes for
+    block_m), or the whole (N, K) dim.  Small FPGA-style arrays therefore
+    run as one full-width tile; the cycle model keeps the FINN folding.
     """
-    if mode == "xnor" or (packed and mode == "binary"):
-        bkw = max(1, fold.simd // WORD_BITS)
-        return {"block_m": m, "block_n": max(8, fold.pe), "block_kw": bkw}
-    bk = max(8, fold.simd)
-    if packed:  # 2-bit lane storage: whole bytes per K step
-        bk = -(-bk // 4) * 4
-    return {"block_m": m, "block_n": max(8, fold.pe), "block_k": bk}
+    if steps_in_words(mode, packed):
+        want = {"block_m": m, "block_n": fold.pe,
+                "block_kw": -(-fold.simd // WORD_BITS)}
+    else:
+        want = {"block_m": m, "block_n": fold.pe, "block_k": fold.simd}
+    return legal_blocks(want, n, k, mode, packed=packed)
 
 
 def block_candidates(
@@ -154,39 +181,28 @@ def block_candidates(
 ) -> list[dict[str, int]]:
     """Enumerate the legal Pallas tile schedules for an (N, K) layer.
 
-    The candidate axes come from the layer's folding divisors, clamped to
-    the TPU minima exactly like :func:`to_tpu_blocks` (block_n/block_k >= 8),
-    plus the full-MXU defaults -- so the heuristic schedule is always in the
-    set and the autotuner can only match or beat it.  ``block_kw`` (xnor and
-    the packed binary datapath) ranges over divisors of the packed word
-    count; packed 2-bit block_k is held to whole bytes.  Candidates are
-    unique dicts; ordering/pruning is the caller's job
-    (``repro.core.autotune``).
+    The candidate axes come from the layer's folding divisors plus the
+    full-MXU defaults, each rounded to the chip's layout by
+    :func:`legal_blocks` exactly like :func:`to_tpu_blocks` -- so the
+    heuristic schedule is always in the set, the autotuner can only match
+    or beat it, and it never times a tile the chip's compiler refuses.
+    ``block_kw`` (xnor and the packed binary datapath) ranges over
+    divisors of the packed word count.  Candidates are unique dicts;
+    ordering/pruning is the caller's job (``repro.core.autotune``).
     """
-    bns = sorted({max(8, d) for d in divisors(n)} | {128})
-    bns = [b for b in bns if b <= max(max_block, 8)]
-    out: list[dict[str, int]] = []
-    if mode == "xnor" or (packed and mode == "binary"):
-        n_words = -(-k // WORD_BITS)
-        bkws = sorted({d for d in divisors(n_words)} | {min(8, n_words)})
-        for bm in block_ms:
-            for bn in bns:
-                for bkw in bkws:
-                    out.append({"block_m": bm, "block_n": bn, "block_kw": bkw})
+    bns = [b for b in divisors(n) + [128] if b <= max(max_block, 8)]
+    if steps_in_words(mode, packed):
+        kkey, ks = "block_kw", divisors(num_words(k)) + [8]
     else:
-        bks = sorted({max(8, d) for d in divisors(k)} | {128, min(512, max(8, k))})
-        bks = [b for b in bks if b <= max(max_block, 8)]
-        if packed:  # 2-bit lane storage: whole bytes per K step
-            bks = sorted({-(-b // 4) * 4 for b in bks})
-        for bm in block_ms:
-            for bn in bns:
-                for bk in bks:
-                    out.append({"block_m": bm, "block_n": bn, "block_k": bk})
-    seen: set[tuple] = set()
-    uniq = []
-    for c in out:
-        key = tuple(sorted(c.items()))
-        if key not in seen:
-            seen.add(key)
-            uniq.append(c)
-    return uniq
+        kkey = "block_k"
+        ks = [b for b in divisors(k) + [128, min(512, k)]
+              if b <= max(max_block, 8)]
+    out: list[dict[str, int]] = []
+    for bm in block_ms:
+        for bn in bns:
+            for bk in ks:
+                c = legal_blocks({"block_m": bm, "block_n": bn, kkey: bk},
+                                 n, k, mode, packed=packed)
+                if c not in out:
+                    out.append(c)
+    return out

@@ -20,7 +20,13 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from repro.core.folding import Folding, choose_folding, to_tpu_blocks
+from repro.core.folding import (
+    Folding,
+    choose_folding,
+    legal_blocks,
+    steps_in_words,
+    to_tpu_blocks,
+)
 from repro.core.quantize import QTensor, int_bounds, quantize_weights
 from repro.core.resource_model import MVUResources, mvu_resources
 from repro.core.thresholds import integerize_thresholds
@@ -48,7 +54,7 @@ class KernelBlocks:
         dense path ignores ``rows_per_tile``, the conv path ignores the K
         blocks -- both accept the full set).  The packed binary datapath
         steps K in 32-bit words like xnor, so it takes ``block_kw``."""
-        if mode == "xnor" or (packed and mode == "binary"):
+        if steps_in_words(mode, packed):
             out = {"block_m": self.block_m, "block_n": self.block_n,
                    "block_kw": self.block_kw}
         else:
@@ -88,10 +94,13 @@ class MVUConfig:
         return choose_folding(self.out_features, self.in_features)
 
     def kernel_blocks(self) -> dict[str, int]:
+        """The chip-legal tile the kernels run (``folding.legal_blocks``)."""
+        n, k = self.out_features, self.in_features
         if self.blocks is not None:
-            return self.blocks.as_kwargs(self.mode, self.packed)
+            return legal_blocks(self.blocks.as_kwargs(self.mode, self.packed),
+                                n, k, self.mode, packed=self.packed)
         return to_tpu_blocks(self.resolved_folding(), self.mode, self.block_m,
-                             packed=self.packed)
+                             n=n, k=k, packed=self.packed)
 
 
 @jax.tree_util.register_pytree_node_class

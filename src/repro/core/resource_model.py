@@ -25,9 +25,12 @@ import dataclasses
 from repro.core.folding import (
     Folding,
     input_buffer_depth,
+    legal_blocks,
+    steps_in_words,
     to_tpu_blocks,
     weight_mem_depth,
 )
+from repro.kernels._common import VMEM_LIMIT_BYTES
 from repro.kernels.packing import WORD_BITS, num_int2_bytes, num_words
 
 # TPU v5e hardware constants (roofline terms use the same numbers).
@@ -35,7 +38,7 @@ PEAK_BF16_FLOPS = 197e12
 PEAK_INT8_OPS = 394e12
 HBM_BW = 819e9
 ICI_BW_PER_LINK = 50e9
-VMEM_BYTES = 64 * 2**20  # conservative per-core working budget
+VMEM_BYTES = VMEM_LIMIT_BYTES  # the kernels' scoped VMEM limit
 CLOCK_HZ = 940e6  # v5e core clock, for cycle -> ns conversions
 
 
@@ -70,7 +73,7 @@ def weight_resident_bytes(n: int, k: int, mode: str, packed: bool) -> int:
     stores uint32 bitplanes (8x smaller than int8 rows); packed standard
     stores 4x 2-bit lanes per byte.
     """
-    if mode == "xnor" or (packed and mode == "binary"):
+    if steps_in_words(mode, packed):
         return n * num_words(k) * 4
     if packed:
         return n * num_int2_bytes(k)
@@ -94,47 +97,49 @@ def mvu_resources(
     """Closed-form resource estimate for one MVU layer instance.
 
     The VMEM working set (``lut_bytes``) is computed from the *actual*
-    kernel blocks, not the raw folding: ``to_tpu_blocks`` clamps ``block_n``
-    and ``block_k`` up to TPU-friendly minima (8 sublanes), and the kernel
-    pads K up to a whole number of ``block_k`` steps while keeping the A
-    tile full-K resident in int8.  Pass ``blocks`` to estimate an explicit
-    (e.g. autotuned) schedule; otherwise the folding's derived blocks are
-    used.  ``packed`` models the bit-packed datapath: the weight tile (and
-    the HBM-resident ``weight_bytes``) shrink by the packing factor while
-    the A tile widens to the padded word span.  BRAM/cycle terms stay on
-    the folding abstraction (paper Eq. 1/2).
+    kernel blocks, not the raw folding: ``folding.legal_blocks`` rounds
+    ``block_n`` and the K step to the chip's layout, and the kernel streams
+    one (block_m, K-step) input tile per grid step.  Pass ``blocks`` to
+    estimate an explicit (e.g. autotuned) schedule; otherwise the folding's
+    derived blocks are used.  ``packed`` models the bit-packed datapath:
+    the weight tile (and the HBM-resident ``weight_bytes``) shrink by the
+    packing factor, and the in-VMEM unpack of one weight tile is counted.
+    BRAM/cycle terms stay on the folding abstraction (paper Eq. 1/2).
     """
     wb = weight_bits / 8.0
     ab = _act_bytes(mode, act_bits)
     if blocks is None:
-        blocks = to_tpu_blocks(fold, mode, block_m, packed=packed)
-    block_m = blocks.get("block_m", block_m)
+        blocks = to_tpu_blocks(fold, mode, block_m, n=n, k=k, packed=packed)
+    blocks = legal_blocks(blocks, n, k, mode, packed=packed)
+    block_m = blocks["block_m"]
     bn = blocks["block_n"]
 
     if mode == "xnor":
-        # packed-word datapath: operands live as uint32 words in VMEM
-        bkw = blocks.get("block_kw", max(1, fold.simd // WORD_BITS))
-        kw = -(-k // WORD_BITS)
-        a_tile = block_m * (-(-kw // bkw) * bkw) * 4  # packed input, full K
+        # packed-word datapath: operands live as uint32 words in VMEM, and
+        # the (bm, bn, bkw) xnor/popcount intermediate is the largest term
+        bkw = blocks["block_kw"]
+        a_tile = block_m * bkw * 4
         w_tile = bn * bkw * 4
+        datapath = block_m * bn * bkw * 4
     elif packed and mode == "binary":
-        # bitplane weights stepped in words; A int8 over the padded span
-        bkw = blocks.get("block_kw", max(1, fold.simd // WORD_BITS))
-        kw = num_words(k)
-        a_tile = block_m * (-(-kw // bkw) * bkw) * WORD_BITS * 1
+        # bitplane weights stepped in words, unpacked to (bn, bkw*32) int8
+        bkw = blocks["block_kw"]
+        a_tile = block_m * bkw * WORD_BITS
         w_tile = bn * bkw * 4
+        datapath = bn * bkw * WORD_BITS * (4 + 1)
     else:
-        # int8 operands on the MXU path regardless of logical weight_bits;
-        # A is full-K resident, padded up to whole block_k steps
-        bk = blocks.get("block_k", max(8, fold.simd))
-        a_tile = block_m * (-(-k // bk) * bk) * 1
-        # packed standard: the weight tile is 2-bit lanes, 4 per byte
-        w_tile = bn * (bk // 4 if packed else bk) * 1
+        # int8 operands on the MXU path regardless of logical weight_bits
+        bk = blocks["block_k"]
+        a_tile = block_m * bk
+        # packed standard: the weight tile is 2-bit lanes, 4 per byte,
+        # sign-extended through int32 into an int8 (bn, bk) tile
+        w_tile = bn * (bk // 4 if packed else bk)
+        datapath = bn * bk * (4 + 1) if packed else 0
     acc = block_m * bn * 4  # int32 PE accumulators
     thr = bn * n_thresh * 4
     out_tile = block_m * bn * 4
 
-    lut = int(a_tile + w_tile + acc + out_tile + thr)
+    lut = int(a_tile + w_tile + datapath + acc + out_tile + thr)
     ff = int(acc + 64)  # accumulators + FSM/counter state
     weight_store = int(n * k * wb)
     in_buf = int(k * ab)

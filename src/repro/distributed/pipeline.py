@@ -25,7 +25,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def stage_params_split(params_stacked, n_stages: int):
@@ -92,12 +91,12 @@ def pipeline_apply(
         return out
 
     pspec_params = jax.tree.map(lambda _: P(axis), stage_params)
-    fn = shard_map(
+    fn = jax.shard_map(
         stage_fn,
         mesh=mesh,
         in_specs=(pspec_params, P()),
         out_specs=P(axis),
-        check_rep=False,
+        check_vma=False,
     )
     stacked = fn(stage_params, x)  # (n_stages * n_micro, mb, ...)
     return stacked[(n_stages - 1) * n_micro :]

@@ -18,28 +18,29 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._common import CompilerParams, epilogue_write, pad_to, std_grid
+from repro.kernels._common import (
+    by_int8_digits,
+    compiler_params,
+    epilogue_operands,
+    epilogue_write,
+    fits_int8,
+    pad_to,
+    split_refs,
+    std_grid,
+)
+from repro.kernels.mvu_int import fit_blocks
 
 
-def _kernel(*refs, block_k: int, has_thresh: bool, has_scale: bool):
-    if has_thresh:
-        a_ref, w_ref, t_ref, o_ref, acc_ref = refs
-        s_ref = None
-    elif has_scale:
-        a_ref, w_ref, s_ref, o_ref, acc_ref = refs
-        t_ref = None
-    else:
-        a_ref, w_ref, o_ref, acc_ref = refs
-        t_ref = s_ref = None
-
+def _kernel(*refs, has_thresh: bool, has_scale: bool):
+    (a_ref, w_ref), t_ref, s_ref, (o_ref, acc_ref) = split_refs(
+        refs, 2, has_thresh, has_scale)
     k = pl.program_id(2)
-    nk = pl.num_programs(2)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    a_blk = a_ref[:, pl.ds(k * block_k, block_k)]  # (bm, bk) int8
+    a_blk = a_ref[...]  # (bm, bk) int8
     w_blk = w_ref[...]  # (bn, bk) int8 in {0,1}
     dot = jax.lax.dot_general(
         a_blk, w_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.int32
@@ -48,7 +49,7 @@ def _kernel(*refs, block_k: int, has_thresh: bool, has_scale: bool):
     rowsum = jnp.sum(a_blk.astype(jnp.int32), axis=1, keepdims=True)
     acc_ref[...] += 2 * dot - rowsum
 
-    @pl.when(k == nk - 1)
+    @pl.when(k == pl.num_programs(2) - 1)
     def _done():
         epilogue_write(o_ref, acc_ref[...], t_ref, s_ref)
 
@@ -70,54 +71,42 @@ def mvu_binary_pallas(
 ) -> jax.Array:
     """out[M,N] = epilogue(A[M,K] . (2*W01[N,K]-1)^T).
 
-    a: (M, K) int8 activations; w_bits: (N, K) int8 in {0,1}.
+    a: (M, K) integer activations (int8 runs one MXU pass, a wider dtype
+    one per base-256 digit); w_bits: (N, K) int8 in {0,1}.
     """
-    if thresholds is not None and out_scale is not None:
-        raise ValueError("thresholds and out_scale are mutually exclusive")
     m, k = a.shape
     n, k2 = w_bits.shape
     assert k == k2
+    if not fits_int8(a.dtype):
+        return by_int8_digits(
+            lambda d: mvu_binary_pallas(d, w_bits, block_m=block_m,
+                                        block_n=block_n, block_k=block_k,
+                                        interpret=interpret),
+            a, thresholds, out_scale)
+    bm, bn, bk = fit_blocks(n, k, m=m, block_m=block_m, block_n=block_n,
+                            block_k=block_k).values()
 
-    a_p = pad_to(pad_to(a, 0, block_m), 1, block_k)
-    w_p = pad_to(pad_to(w_bits.astype(jnp.int8), 0, block_n), 1, block_k)
+    a_p = pad_to(pad_to(a.astype(jnp.int8), 0, bm), 1, bk)
+    w_p = pad_to(pad_to(w_bits.astype(jnp.int8), 0, bn), 1, bk)
     mp, kp = a_p.shape
     np_, _ = w_p.shape
-    grid = std_grid(mp, np_, kp, block_m, block_n, block_k)
-
-    in_specs = [
-        pl.BlockSpec((block_m, kp), lambda mi, ni, ki: (mi, 0)),
-        pl.BlockSpec((block_n, block_k), lambda mi, ni, ki: (ni, ki)),
-    ]
-    operands = [a_p, w_p]
-    has_thresh = thresholds is not None
-    has_scale = out_scale is not None
-    if has_thresh:
-        t_p = pad_to(thresholds.astype(jnp.int32), 0, block_n)
-        nt = t_p.shape[1]
-        in_specs.append(pl.BlockSpec((block_n, nt), lambda mi, ni, ki: (ni, 0)))
-        operands.append(t_p)
-        out_dtype = jnp.int32
-    elif has_scale:
-        s_p = pad_to(out_scale.reshape(-1, 1).astype(jnp.float32), 0, block_n, value=1)
-        in_specs.append(pl.BlockSpec((block_n, 1), lambda mi, ni, ki: (ni, 0)))
-        operands.append(s_p)
-        out_dtype = jnp.float32
-    else:
-        out_dtype = jnp.int32
+    epi_specs, epi_ops, out_dtype = epilogue_operands(
+        thresholds, out_scale, bn, lambda mi, ni, ki: (ni, 0))
+    has_thresh, has_scale = thresholds is not None, out_scale is not None
 
     out = pl.pallas_call(
-        functools.partial(
-            _kernel, block_k=block_k, has_thresh=has_thresh, has_scale=has_scale
-        ),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((block_m, block_n), lambda mi, ni, ki: (mi, ni)),
+        functools.partial(_kernel, has_thresh=has_thresh, has_scale=has_scale),
+        grid=std_grid(mp, np_, kp, bm, bn, bk),
+        in_specs=[
+            pl.BlockSpec((bm, bk), lambda mi, ni, ki: (mi, ki)),
+            pl.BlockSpec((bn, bk), lambda mi, ni, ki: (ni, ki)),
+            *epi_specs,
+        ],
+        out_specs=pl.BlockSpec((bm, bn), lambda mi, ni, ki: (mi, ni)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
-        scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-        ),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
+        compiler_params=compiler_params("parallel", "arbitrary", "arbitrary"),
         interpret=interpret,
         name="mvu_binary",
-    )(*operands)
+    )(a_p, w_p, *epi_ops)
     return out[:m, :n]

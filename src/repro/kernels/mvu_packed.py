@@ -30,13 +30,20 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels._common import (
-    CompilerParams,
+    LANE,
+    SUBLANE,
+    by_int8_digits,
+    compiler_params,
     default_interpret,
+    epilogue_operands,
     epilogue_write,
+    fit_block,
+    fits_int8,
     pad_to,
+    split_refs,
     std_grid,
 )
-from repro.kernels import packing, ref
+from repro.kernels import mvu_xnor, packing, ref
 from repro.kernels.packing import INT2_PER_BYTE, WORD_BITS, pad_correction
 
 
@@ -79,31 +86,21 @@ def mvu_xnor_popcount_xla(
 
 
 # ----------------------------------------------------------- binary / pallas
-def _binary_kernel(*refs, block_kw: int, has_thresh: bool, has_scale: bool):
-    if has_thresh:
-        a_ref, w_ref, t_ref, o_ref, acc_ref = refs
-        s_ref = None
-    elif has_scale:
-        a_ref, w_ref, s_ref, o_ref, acc_ref = refs
-        t_ref = None
-    else:
-        a_ref, w_ref, o_ref, acc_ref = refs
-        t_ref = s_ref = None
-
+def _binary_kernel(*refs, has_thresh: bool, has_scale: bool):
+    (a_ref, w_ref), t_ref, s_ref, (o_ref, acc_ref) = split_refs(
+        refs, 2, has_thresh, has_scale)
     k = pl.program_id(2)
-    nk = pl.num_programs(2)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    bk = block_kw * WORD_BITS
-    a_blk = a_ref[:, pl.ds(k * bk, bk)]  # (bm, bkw*32) int8
+    a_blk = a_ref[...]  # (bm, bkw*32) int8
     w_blk = w_ref[...]  # (bn, bkw) uint32 bitplanes
     # in-VMEM unpack of one weight tile: (bn, bkw, 32) bits -> (bn, bkw*32)
     shifts = jax.lax.broadcasted_iota(jnp.uint32, (1, 1, WORD_BITS), 2)
     w01 = ((w_blk[:, :, None] >> shifts) & jnp.uint32(1)).astype(jnp.int8)
-    w01 = w01.reshape(w_blk.shape[0], bk)
+    w01 = w01.reshape(w_blk.shape[0], -1)
     dot = jax.lax.dot_general(
         a_blk, w01, (((1,), (1,)), ((), ())), preferred_element_type=jnp.int32
     )
@@ -112,7 +109,7 @@ def _binary_kernel(*refs, block_kw: int, has_thresh: bool, has_scale: bool):
     rowsum = jnp.sum(a_blk.astype(jnp.int32), axis=1, keepdims=True)
     acc_ref[...] += 2 * dot - rowsum
 
-    @pl.when(k == nk - 1)
+    @pl.when(k == pl.num_programs(2) - 1)
     def _done():
         epilogue_write(o_ref, acc_ref[...], t_ref, s_ref)
 
@@ -135,63 +132,50 @@ def mvu_binary_packed_pallas(
 ) -> jax.Array:
     """out[M,N] = epilogue(A[M,K] . (2*W01[N,K]-1)^T) from bitplane weights.
 
-    a: (M, K) int8 activations; w_packed: (N, ceil(K/32)) uint32 bitplanes
+    a: (M, K) integer activations (int8 runs one MXU pass, a wider dtype
+    one per base-256 digit); w_packed: (N, ceil(K/32)) uint32 bitplanes
     of the {0,1} weight coding (:func:`packing.pack_bits`).  The weight
     tile is unpacked inside the kernel, so the HBM-resident weights stay
-    32x smaller than the canonical int8 form.
+    32x smaller than the canonical int8 form.  Tiles as ``mvu_xnor``.
     """
-    if thresholds is not None and out_scale is not None:
-        raise ValueError("thresholds and out_scale are mutually exclusive")
     m, k = a.shape
     n, wd = w_packed.shape
     assert k == k_bits and wd * WORD_BITS >= k
+    if not fits_int8(a.dtype):
+        return by_int8_digits(
+            lambda d: mvu_binary_packed_pallas(
+                d, w_packed, k_bits, block_m=block_m, block_n=block_n,
+                block_kw=block_kw, interpret=interpret),
+            a, thresholds, out_scale)
+    bm, bn, bkw = mvu_xnor.fit_blocks(
+        n, wd * WORD_BITS, m=m, block_m=block_m, block_n=block_n,
+        block_kw=block_kw).values()
 
-    w_p = pad_to(pad_to(w_packed, 0, block_n), 1, block_kw)
+    w_p = pad_to(pad_to(w_packed, 0, bn), 1, bkw)
     np_, wdp = w_p.shape
     # activations padded out to the full unpacked span of the padded words
-    a_p = pad_to(pad_to(a.astype(jnp.int8), 0, block_m), 1, wdp * WORD_BITS)
+    a_p = pad_to(pad_to(a.astype(jnp.int8), 0, bm), 1, wdp * WORD_BITS)
     mp, _ = a_p.shape
-    grid = std_grid(mp, np_, wdp, block_m, block_n, block_kw)
-
-    in_specs = [
-        pl.BlockSpec((block_m, wdp * WORD_BITS), lambda mi, ni, ki: (mi, 0)),
-        pl.BlockSpec((block_n, block_kw), lambda mi, ni, ki: (ni, ki)),
-    ]
-    operands = [a_p, w_p]
-    has_thresh = thresholds is not None
-    has_scale = out_scale is not None
-    if has_thresh:
-        t_p = pad_to(thresholds.astype(jnp.int32), 0, block_n)
-        nt = t_p.shape[1]
-        in_specs.append(pl.BlockSpec((block_n, nt), lambda mi, ni, ki: (ni, 0)))
-        operands.append(t_p)
-        out_dtype = jnp.int32
-    elif has_scale:
-        s_p = pad_to(out_scale.reshape(-1, 1).astype(jnp.float32), 0, block_n, value=1)
-        in_specs.append(pl.BlockSpec((block_n, 1), lambda mi, ni, ki: (ni, 0)))
-        operands.append(s_p)
-        out_dtype = jnp.float32
-    else:
-        out_dtype = jnp.int32
+    epi_specs, epi_ops, out_dtype = epilogue_operands(
+        thresholds, out_scale, bn, lambda mi, ni, ki: (ni, 0))
+    has_thresh, has_scale = thresholds is not None, out_scale is not None
 
     out = pl.pallas_call(
         functools.partial(
-            _binary_kernel,
-            block_kw=block_kw,
-            has_thresh=has_thresh,
-            has_scale=has_scale,
-        ),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((block_m, block_n), lambda mi, ni, ki: (mi, ni)),
+            _binary_kernel, has_thresh=has_thresh, has_scale=has_scale),
+        grid=std_grid(mp, np_, wdp, bm, bn, bkw),
+        in_specs=[
+            pl.BlockSpec((bm, bkw * WORD_BITS), lambda mi, ni, ki: (mi, ki)),
+            pl.BlockSpec((bn, bkw), lambda mi, ni, ki: (ni, ki)),
+            *epi_specs,
+        ],
+        out_specs=pl.BlockSpec((bm, bn), lambda mi, ni, ki: (mi, ni)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
-        scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-        ),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
+        compiler_params=compiler_params("parallel", "arbitrary", "arbitrary"),
         interpret=interpret,
         name="mvu_binary_packed",
-    )(*operands)
+    )(a_p, w_p, *epi_ops)
     return out[:m, :n]
 
 
@@ -209,36 +193,41 @@ def mvu_binary_packed_xla(
 
 
 # ------------------------------------------------------------- 2-bit / pallas
-def _int2_kernel(*refs, block_kb: int, has_thresh: bool, has_scale: bool):
-    if has_thresh:
-        a_ref, w_ref, t_ref, o_ref, acc_ref = refs
-        s_ref = None
-    elif has_scale:
-        a_ref, w_ref, s_ref, o_ref, acc_ref = refs
-        t_ref = None
-    else:
-        a_ref, w_ref, o_ref, acc_ref = refs
-        t_ref = s_ref = None
+def fit_int2_blocks(n: int, k: int, *, block_m: int, block_n: int,
+                    block_k: int, m: int | None = None) -> dict[str, int]:
+    """The tile the 2-bit-lane kernel runs for a requested schedule over
+    ``k`` synapses: ``block_k`` counts synapse lanes and rounds up to whole
+    legal byte tiles (4 lanes per byte); ``m=None`` leaves block_m
+    uncapped by the batch."""
+    bkb = fit_block(-(-block_k // INT2_PER_BYTE), packing.num_int2_bytes(k),
+                    LANE)
+    return {"block_m": fit_block(block_m, m, SUBLANE),
+            "block_n": fit_block(block_n, n, LANE),
+            "block_k": bkb * INT2_PER_BYTE}
 
+
+def _int2_kernel(*refs, has_thresh: bool, has_scale: bool):
+    (a_ref, w_ref), t_ref, s_ref, (o_ref, acc_ref) = split_refs(
+        refs, 2, has_thresh, has_scale)
     k = pl.program_id(2)
-    nk = pl.num_programs(2)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    bk = block_kb * INT2_PER_BYTE
-    a_blk = a_ref[:, pl.ds(k * bk, bk)]  # (bm, bkb*4) int8
-    w_blk = w_ref[...]  # (bn, bkb) uint8 2-bit lanes
-    # in-VMEM sign-extending unpack: (bn, bkb, 4) fields -> (bn, bkb*4)
-    shifts = 2 * jax.lax.broadcasted_iota(jnp.uint8, (1, 1, INT2_PER_BYTE), 2)
-    fields = ((w_blk[:, :, None] >> shifts) & jnp.uint8(0x3)).astype(jnp.int8)
-    w2 = jnp.where(fields >= 2, fields - 4, fields).reshape(w_blk.shape[0], bk)
+    # in-VMEM sign-extending unpack: (bn, bkb, 4) fields -> (bn, bkb*4),
+    # widened to int32 first (the chip has no 3-D int8 layout for it)
+    w_blk = w_ref[...].astype(jnp.int32)  # (bn, bkb) 2-bit lanes
+    shifts = 2 * jax.lax.broadcasted_iota(jnp.int32, (1, 1, INT2_PER_BYTE), 2)
+    fields = (w_blk[:, :, None] >> shifts) & 0x3
+    w2 = jnp.where(fields >= 2, fields - 4, fields)
+    w2 = w2.reshape(w_blk.shape[0], -1).astype(jnp.int8)
     acc_ref[...] += jax.lax.dot_general(
-        a_blk, w2, (((1,), (1,)), ((), ())), preferred_element_type=jnp.int32
+        a_ref[...], w2, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.int32,
     )
 
-    @pl.when(k == nk - 1)
+    @pl.when(k == pl.num_programs(2) - 1)
     def _done():
         epilogue_write(o_ref, acc_ref[...], t_ref, s_ref)
 
@@ -261,65 +250,51 @@ def mvu_int2_packed_pallas(
 ) -> jax.Array:
     """out[M,N] = epilogue(A[M,K] . W2[N,K]^T) from 2-bit lane weights.
 
-    a: (M, K) int8 activations; w_packed: (N, ceil(K/4)) uint8 holding four
+    a: (M, K) integer activations (int8 runs one MXU pass, a wider dtype
+    one per base-256 digit); w_packed: (N, ceil(K/4)) uint8 holding four
     signed 2-bit two's-complement lanes per byte (:func:`packing.pack_int2`).
-    ``block_k`` counts synapse lanes (must be a multiple of 4); padded lanes
-    decode to weight 0 and contribute nothing.
+    Tiles by :func:`fit_int2_blocks`; padded lanes decode to weight 0 and
+    contribute nothing.
     """
-    if thresholds is not None and out_scale is not None:
-        raise ValueError("thresholds and out_scale are mutually exclusive")
-    if block_k % INT2_PER_BYTE:
-        raise ValueError(f"block_k must be a multiple of {INT2_PER_BYTE}")
     m, k = a.shape
     n, bd = w_packed.shape
     assert k == k_bits and bd * INT2_PER_BYTE >= k
-    block_kb = block_k // INT2_PER_BYTE
+    if not fits_int8(a.dtype):
+        return by_int8_digits(
+            lambda d: mvu_int2_packed_pallas(
+                d, w_packed, k_bits, block_m=block_m, block_n=block_n,
+                block_k=block_k, interpret=interpret),
+            a, thresholds, out_scale)
+    bm, bn, bk = fit_int2_blocks(
+        n, bd * INT2_PER_BYTE, m=m, block_m=block_m, block_n=block_n,
+        block_k=block_k).values()
+    bkb = bk // INT2_PER_BYTE
 
-    w_p = pad_to(pad_to(w_packed, 0, block_n), 1, block_kb)
+    w_p = pad_to(pad_to(w_packed, 0, bn), 1, bkb)
     np_, bdp = w_p.shape
-    a_p = pad_to(pad_to(a.astype(jnp.int8), 0, block_m), 1, bdp * INT2_PER_BYTE)
+    a_p = pad_to(pad_to(a.astype(jnp.int8), 0, bm), 1, bdp * INT2_PER_BYTE)
     mp, _ = a_p.shape
-    grid = std_grid(mp, np_, bdp, block_m, block_n, block_kb)
-
-    in_specs = [
-        pl.BlockSpec((block_m, bdp * INT2_PER_BYTE), lambda mi, ni, ki: (mi, 0)),
-        pl.BlockSpec((block_n, block_kb), lambda mi, ni, ki: (ni, ki)),
-    ]
-    operands = [a_p, w_p]
-    has_thresh = thresholds is not None
-    has_scale = out_scale is not None
-    if has_thresh:
-        t_p = pad_to(thresholds.astype(jnp.int32), 0, block_n)
-        nt = t_p.shape[1]
-        in_specs.append(pl.BlockSpec((block_n, nt), lambda mi, ni, ki: (ni, 0)))
-        operands.append(t_p)
-        out_dtype = jnp.int32
-    elif has_scale:
-        s_p = pad_to(out_scale.reshape(-1, 1).astype(jnp.float32), 0, block_n, value=1)
-        in_specs.append(pl.BlockSpec((block_n, 1), lambda mi, ni, ki: (ni, 0)))
-        operands.append(s_p)
-        out_dtype = jnp.float32
-    else:
-        out_dtype = jnp.int32
+    epi_specs, epi_ops, out_dtype = epilogue_operands(
+        thresholds, out_scale, bn, lambda mi, ni, ki: (ni, 0))
+    has_thresh, has_scale = thresholds is not None, out_scale is not None
 
     out = pl.pallas_call(
         functools.partial(
-            _int2_kernel,
-            block_kb=block_kb,
-            has_thresh=has_thresh,
-            has_scale=has_scale,
-        ),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((block_m, block_n), lambda mi, ni, ki: (mi, ni)),
+            _int2_kernel, has_thresh=has_thresh, has_scale=has_scale),
+        grid=std_grid(mp, np_, bdp, bm, bn, bkb),
+        in_specs=[
+            pl.BlockSpec((bm, bkb * INT2_PER_BYTE),
+                         lambda mi, ni, ki: (mi, ki)),
+            pl.BlockSpec((bn, bkb), lambda mi, ni, ki: (ni, ki)),
+            *epi_specs,
+        ],
+        out_specs=pl.BlockSpec((bm, bn), lambda mi, ni, ki: (mi, ni)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
-        scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-        ),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
+        compiler_params=compiler_params("parallel", "arbitrary", "arbitrary"),
         interpret=interpret,
         name="mvu_int2_packed",
-    )(*operands)
+    )(a_p, w_p, *epi_ops)
     return out[:m, :n]
 
 

@@ -26,41 +26,46 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels._common import (
-    CompilerParams,
+    LANE,
+    SUBLANE,
+    compiler_params,
+    epilogue_operands,
     epilogue_write,
+    fit_block,
     pad_to,
+    split_refs,
     std_grid,
     swar_popcount,
 )
-from repro.kernels.packing import WORD_BITS, pad_correction
+from repro.kernels.packing import WORD_BITS, num_words, pad_correction
 
 
-def _kernel(*refs, block_kw: int, correction: int,
-            has_thresh: bool, has_scale: bool):
-    if has_thresh:
-        a_ref, w_ref, t_ref, o_ref, acc_ref = refs
-        s_ref = None
-    elif has_scale:
-        a_ref, w_ref, s_ref, o_ref, acc_ref = refs
-        t_ref = None
-    else:
-        a_ref, w_ref, o_ref, acc_ref = refs
-        t_ref = s_ref = None
+def fit_blocks(n: int, k: int, *, block_m: int, block_n: int, block_kw: int,
+               m: int | None = None) -> dict[str, int]:
+    """The tile a kernel stepping K in 32-bit words runs for a requested
+    schedule over ``k`` synapses (``mvu_xnor`` and the packed binary
+    kernel); ``m=None`` leaves block_m uncapped by the batch."""
+    return {"block_m": fit_block(block_m, m, SUBLANE),
+            "block_n": fit_block(block_n, n, LANE),
+            "block_kw": fit_block(block_kw, num_words(k), LANE)}
 
+
+def _kernel(*refs, correction: int, has_thresh: bool, has_scale: bool):
+    (a_ref, w_ref), t_ref, s_ref, (o_ref, acc_ref) = split_refs(
+        refs, 2, has_thresh, has_scale)
     k = pl.program_id(2)
-    nk = pl.num_programs(2)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    a_blk = a_ref[:, pl.ds(k * block_kw, block_kw)]  # (bm, bkw) uint32
+    a_blk = a_ref[...]  # (bm, bkw) uint32
     w_blk = w_ref[...]  # (bn, bkw) uint32
     # (bm, bn, bkw) xnor + popcount, reduced over the word axis on the VPU.
     xnor = ~(a_blk[:, None, :] ^ w_blk[None, :, :])
     acc_ref[...] += jnp.sum(swar_popcount(xnor), axis=-1, dtype=jnp.int32)
 
-    @pl.when(k == nk - 1)
+    @pl.when(k == pl.num_programs(2) - 1)
     def _done():
         # bipolar dot over the true K bits (packing.pad_correction)
         dot = 2 * acc_ref[...] - correction
@@ -84,57 +89,38 @@ def mvu_xnor_pallas(
     interpret: bool = False,
 ) -> jax.Array:
     """Bipolar out[M,N] from packed a (M, Wd) uint32 and w (N, Wd) uint32."""
-    if thresholds is not None and out_scale is not None:
-        raise ValueError("thresholds and out_scale are mutually exclusive")
     m, wd = a_packed.shape
     n, wd2 = w_packed.shape
     assert wd == wd2
+    bm, bn, bkw = fit_blocks(n, wd * WORD_BITS, m=m, block_m=block_m,
+                             block_n=block_n, block_kw=block_kw).values()
 
-    a_p = pad_to(pad_to(a_packed, 0, block_m), 1, block_kw)
-    w_p = pad_to(pad_to(w_packed, 0, block_n), 1, block_kw)
+    a_p = pad_to(pad_to(a_packed, 0, bm), 1, bkw)
+    w_p = pad_to(pad_to(w_packed, 0, bn), 1, bkw)
     mp, wdp = a_p.shape
     np_, _ = w_p.shape
-    kp_bits = wdp * WORD_BITS
-    grid = std_grid(mp, np_, wdp, block_m, block_n, block_kw)
-
-    in_specs = [
-        pl.BlockSpec((block_m, wdp), lambda mi, ni, ki: (mi, 0)),
-        pl.BlockSpec((block_n, block_kw), lambda mi, ni, ki: (ni, ki)),
-    ]
-    operands = [a_p, w_p]
-    has_thresh = thresholds is not None
-    has_scale = out_scale is not None
-    if has_thresh:
-        t_p = pad_to(thresholds.astype(jnp.int32), 0, block_n)
-        nt = t_p.shape[1]
-        in_specs.append(pl.BlockSpec((block_n, nt), lambda mi, ni, ki: (ni, 0)))
-        operands.append(t_p)
-        out_dtype = jnp.int32
-    elif has_scale:
-        s_p = pad_to(out_scale.reshape(-1, 1).astype(jnp.float32), 0, block_n, value=1)
-        in_specs.append(pl.BlockSpec((block_n, 1), lambda mi, ni, ki: (ni, 0)))
-        operands.append(s_p)
-        out_dtype = jnp.float32
-    else:
-        out_dtype = jnp.int32
+    epi_specs, epi_ops, out_dtype = epilogue_operands(
+        thresholds, out_scale, bn, lambda mi, ni, ki: (ni, 0))
+    has_thresh, has_scale = thresholds is not None, out_scale is not None
 
     out = pl.pallas_call(
         functools.partial(
             _kernel,
-            block_kw=block_kw,
-            correction=pad_correction(k_bits, kp_bits),
+            correction=pad_correction(k_bits, wdp * WORD_BITS),
             has_thresh=has_thresh,
             has_scale=has_scale,
         ),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((block_m, block_n), lambda mi, ni, ki: (mi, ni)),
+        grid=std_grid(mp, np_, wdp, bm, bn, bkw),
+        in_specs=[
+            pl.BlockSpec((bm, bkw), lambda mi, ni, ki: (mi, ki)),
+            pl.BlockSpec((bn, bkw), lambda mi, ni, ki: (ni, ki)),
+            *epi_specs,
+        ],
+        out_specs=pl.BlockSpec((bm, bn), lambda mi, ni, ki: (mi, ni)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
-        scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-        ),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
+        compiler_params=compiler_params("parallel", "arbitrary", "arbitrary"),
         interpret=interpret,
         name="mvu_xnor",
-    )(*operands)
+    )(a_p, w_p, *epi_ops)
     return out[:m, :n]

@@ -16,6 +16,8 @@ the TPU is the deployment target.
 
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 
@@ -29,6 +31,15 @@ from repro.kernels.swu_mvu import conv_mvu_pallas
 
 MODES = ("xnor", "binary", "standard")
 BACKENDS = ("pallas", "xla")
+
+
+def tpu_kernel_names(compiled_text: str) -> list[str]:
+    """Names of the Pallas kernels (``tpu_custom_call`` ops) in the text of
+    a program compiled for the TPU, e.g. ``["mvu_int", "mvu_int"]``.  An
+    interpret-mode or XLA substitute leaves none."""
+    return [m.group(1) for m in re.finditer(
+        r"%([A-Za-z_]\w*?)(?:\.\d+)? = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        compiled_text)]
 
 
 def xnor_mxu(
@@ -51,16 +62,21 @@ def xnor_mxu(
     return mvu_int_pallas(a, w, thresholds, out_scale, interpret=default_interpret())
 
 
-def mvu_layer_fn(mode: str = "standard", *, backend: str = "pallas", **blocks):
+def mvu_layer_fn(mode: str = "standard", *, backend: str = "pallas",
+                 int8_input: bool = False, **blocks):
     """Stage callable for the streaming executors: ``fn(params, x) -> y``.
 
     ``params`` is a dict with ``"w"`` (N, K) plus optionally ``"t"``
     (thresholds) or ``"s"`` (out_scale) — the stackable form used by
     ``repro.core.engine.FusedEngine.as_pipeline`` to run one MVU per
     pipeline stage through ``repro.distributed.pipeline.pipeline_apply``.
+    ``int8_input`` narrows ``x`` to int8 first: only where the caller has
+    proved every value fits (``dataflow.int8_inputs``).
     """
 
     def fn(params, x):
+        if int8_input:
+            x = x.astype(jnp.int8)
         return mvu(
             x,
             params["w"],
@@ -118,7 +134,7 @@ def conv_mvu(
             x, w, kernel=kernel, stride=stride, pad=pad, mode=mode,
             thresholds=thresholds, out_scale=out_scale,
         )
-    del block_k, block_kw  # the fused gather keeps full K resident
+    del block_k, block_kw  # each conv grid step takes every tap's full C
     return conv_mvu_pallas(
         x, w, thresholds, out_scale,
         kernel=kernel, stride=stride, pad=pad, mode=mode,

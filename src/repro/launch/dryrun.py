@@ -257,9 +257,8 @@ def build_cell(cfg, shape_name: str, mesh, *, fsdp: bool | None = None):
 def _compile_cell(cfg, shape_name, mesh, fsdp=None):
     fn, args, donate, shardings, cfg, acct = build_cell(cfg, shape_name, mesh, fsdp=fsdp)
     t0 = time.time()
-    from repro.launch.mesh import use_mesh
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(fn, in_shardings=shardings, donate_argnums=donate)
         lowered = jitted.lower(*args)
         t_lower = time.time() - t0

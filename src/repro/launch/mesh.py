@@ -1,4 +1,4 @@
-"""Production meshes.
+"""The one place meshes are made.
 
 Single pod: (16, 16) ("data", "model") = 256 chips (TPU v5e pod).
 Multi-pod:  (2, 16, 16) ("pod", "data", "model") = 512 chips.
@@ -10,27 +10,21 @@ state (the dry-run sets XLA_FLAGS *before* any jax initialization).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape=(2, 2), axes=("data", "model"), *, devices=None):
+    """A mesh whose axes are all ``Auto``: GSPMD propagates shardings
+    outside ``jax.shard_map``, which is manual over them inside.
+    ``jax.make_mesh`` alone would make them ``Explicit``."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_host_mesh(shape=(2, 2), axes=("data", "model")):
-    """Small mesh over host (CPU) devices for tests/examples."""
-    return jax.make_mesh(shape, axes)
-
-
-def use_mesh(mesh):
-    """Context manager activating ``mesh``, across jax versions.
-
-    ``jax.set_mesh`` only exists on newer jax; a ``Mesh`` has always been
-    its own context manager, so fall back to entering it directly.
-    """
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
+    return make_mesh(shape, axes)
 
 
 def data_axes(mesh) -> tuple[str, ...]:

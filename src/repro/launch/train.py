@@ -23,7 +23,7 @@ from repro.distributed.sharding import (
     batch_shardings,
     param_shardings,
 )
-from repro.launch.mesh import make_host_mesh
+from repro.launch.mesh import make_mesh
 from repro.models.model import Model, build
 from repro.optim import adamw
 
@@ -144,7 +144,7 @@ def main():
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     model = build(cfg)
     d0, d1 = (int(x) for x in args.mesh.split("x"))
-    mesh = make_host_mesh((d0, d1))
+    mesh = make_mesh((d0, d1))
     data = SyntheticLM(cfg.vocab_size, args.seq, args.batch)
     t0 = time.time()
     _, _, hist = train_loop(

@@ -220,10 +220,7 @@ def seq_shard(x: jax.Array) -> jax.Array:
     No-op when no mesh context is active, when "model" is absent, or when
     the sequence does not divide evenly (e.g. decode steps).
     """
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-    except Exception:  # pragma: no cover - old jax
-        return x
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or not getattr(mesh, "axis_names", None):
         return x
     if "model" not in mesh.axis_names or x.ndim < 3:

@@ -288,12 +288,16 @@ def test_resource_model_uses_actual_kernel_blocks():
 
 
 def test_explicit_blocks_override_folding_derivation():
-    cfg = MVUConfig(in_features=64, out_features=32,
-                    blocks=KernelBlocks(block_m=64, block_n=16, block_k=32))
-    assert cfg.kernel_blocks() == {"block_m": 64, "block_n": 16, "block_k": 32}
-    xcfg = MVUConfig(in_features=64, out_features=32, mode="xnor",
-                     blocks=KernelBlocks(block_m=64, block_n=16, block_kw=2))
-    assert xcfg.kernel_blocks() == {"block_m": 64, "block_n": 16, "block_kw": 2}
+    cfg = MVUConfig(in_features=1024, out_features=512,
+                    blocks=KernelBlocks(block_m=64, block_n=256, block_k=256))
+    assert cfg.kernel_blocks() == {"block_m": 64, "block_n": 256, "block_k": 256}
+    xcfg = MVUConfig(in_features=8192, out_features=512, mode="xnor",
+                     blocks=KernelBlocks(block_m=64, block_n=256, block_kw=128))
+    assert xcfg.kernel_blocks() == {"block_m": 64, "block_n": 256, "block_kw": 128}
+    # an explicit schedule is still rounded to the tile the chip runs
+    small = MVUConfig(in_features=64, out_features=32,
+                      blocks=KernelBlocks(block_m=64, block_n=16, block_k=32))
+    assert small.kernel_blocks() == {"block_m": 64, "block_n": 32, "block_k": 64}
 
 
 # ------------------------------------------------------------------ keys

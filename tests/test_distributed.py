@@ -35,7 +35,7 @@ def test_sharded_train_step_runs_and_matches_single_device():
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs import get_reduced
         from repro.models.model import build
-        from repro.launch.mesh import make_host_mesh
+        from repro.launch.mesh import make_mesh
         from repro.launch.train import shard_train_step, init_sharded
         from repro.optim import adamw
 
@@ -44,7 +44,7 @@ def test_sharded_train_step_runs_and_matches_single_device():
         opt_cfg = adamw.AdamWConfig(lr=1e-3, warmup_steps=1, total_steps=10)
         batch = {"tokens": jax.random.randint(jax.random.PRNGKey(9), (8, 33), 0, cfg.vocab_size)}
 
-        mesh = make_host_mesh((4, 2))
+        mesh = make_mesh((4, 2))
         step, p_sh, o_sh, b_sh = shard_train_step(model, mesh, opt_cfg, batch)
         params, opt_state, _, _ = init_sharded(model, mesh)
         p0 = jax.tree.map(lambda x: np.asarray(x).copy(), params)
@@ -77,14 +77,14 @@ def test_moe_and_hybrid_shard_on_mesh():
         import jax, jax.numpy as jnp
         from repro.configs import get_reduced
         from repro.models.model import build
-        from repro.launch.mesh import make_host_mesh
+        from repro.launch.mesh import make_mesh
         from repro.launch.train import shard_train_step, init_sharded
         from repro.optim import adamw
 
         for arch in ["granite-moe-3b-a800m", "jamba-1.5-large-398b"]:
             cfg = get_reduced(arch).replace(dtype="float32")
             model = build(cfg)
-            mesh = make_host_mesh((2, 4))
+            mesh = make_mesh((2, 4))
             batch = {"tokens": jax.random.randint(jax.random.PRNGKey(0), (4, 17), 0, cfg.vocab_size)}
             step, p_sh, o_sh, b_sh = shard_train_step(
                 model, mesh, adamw.AdamWConfig(total_steps=5), batch)
@@ -101,12 +101,12 @@ def test_serve_fns_shard_and_decode():
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs import get_reduced
         from repro.models.model import build
-        from repro.launch.mesh import make_host_mesh
+        from repro.launch.mesh import make_mesh
         from repro.launch.serve import shard_serve_fns
 
         cfg = get_reduced("yi-9b").replace(dtype="float32")
         model = build(cfg)
-        mesh = make_host_mesh((4, 2))
+        mesh = make_mesh((4, 2))
         B, L = 8, 64
         prefill, decode, p_sh, s_sh = shard_serve_fns(model, mesh, B, L)
         params = jax.jit(model.init, out_shardings=p_sh)(jax.random.PRNGKey(0))
@@ -127,13 +127,13 @@ def test_checkpoint_elastic_rescale():
         import tempfile, jax, jax.numpy as jnp, numpy as np
         from repro.configs import get_reduced
         from repro.models.model import build
-        from repro.launch.mesh import make_host_mesh
+        from repro.launch.mesh import make_mesh
         from repro.distributed.sharding import param_shardings
         from repro.checkpoint import ckpt
 
         cfg = get_reduced("yi-9b").replace(dtype="float32")
         model = build(cfg)
-        mesh_a = make_host_mesh((2, 4))
+        mesh_a = make_mesh((2, 4))
         shape = jax.eval_shape(model.init, jax.random.PRNGKey(0))
         params = jax.jit(model.init, out_shardings=param_shardings(mesh_a, shape))(
             jax.random.PRNGKey(0))
@@ -141,7 +141,7 @@ def test_checkpoint_elastic_rescale():
             ckpt.save(d, 7, params)
             assert ckpt.latest_step(d) == 7
             # restore onto a *different* mesh shape (elastic rescale)
-            mesh_b = make_host_mesh((8, 1))
+            mesh_b = make_mesh((8, 1))
             restored = ckpt.restore(d, 7, shape, param_shardings(mesh_b, shape))
             err = max(float(jnp.abs(a - b).max()) for a, b in
                       zip(jax.tree.leaves(params), jax.tree.leaves(restored)))
@@ -156,6 +156,7 @@ def test_pipeline_parallel_matches_sequential():
         import jax, jax.numpy as jnp, numpy as np
         from repro.distributed.pipeline import (
             pipeline_apply, sequential_reference, stage_params_split)
+        from repro.launch.mesh import make_mesh
 
         L, S, n_micro, mb, d = 8, 4, 8, 4, 16
         key = jax.random.PRNGKey(0)
@@ -167,7 +168,7 @@ def test_pipeline_parallel_matches_sequential():
         def layer_fn(p, h):
             return jnp.tanh(h @ p["w"] + p["b"])
 
-        mesh = jax.make_mesh((S,), ("stage",))
+        mesh = make_mesh((S,), ("stage",))
         got = pipeline_apply(layer_fn, stage_params_split(params, S), x, mesh)
         want = sequential_reference(layer_fn, params, x)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
@@ -191,10 +192,10 @@ def test_grad_compression_converges():
     out = run_devices(4, """
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from repro.launch.mesh import make_mesh
         from repro.optim import grad_compress as gc
 
-        mesh = jax.make_mesh((4,), ("data",))
+        mesh = make_mesh((4,), ("data",))
         # toy regression, data-parallel over 4 devices
         k = jax.random.PRNGKey(0)
         w_true = jax.random.normal(k, (16,))
@@ -214,9 +215,9 @@ def test_grad_compression_converges():
                 g = local_grad(w, xb, yb)
                 gg, e2 = gc.psum_compressed({"w": g}, {"w": e[0]}, ("data",))
                 return gg["w"], e2["w"][None]
-            g, errs = shard_map(inner, mesh=mesh,
+            g, errs = jax.shard_map(inner, mesh=mesh,
                 in_specs=(P(), P("data"), P("data"), P("data")),
-                out_specs=(P(), P("data")), check_rep=False)(w, errs, X, y)
+                out_specs=(P(), P("data")), check_vma=False)(w, errs, X, y)
             return w - 0.2 * g, errs
 
         for i in range(200):
@@ -233,16 +234,16 @@ def test_as_pipeline_rejects_unstackable_graphs():
     tests/test_engine.py): heterogeneous ops, heterogeneous MVU shapes,
     mixed epilogue forms, and the xnor packed-width rejection must all fail
     with clear errors before any device work happens."""
-    import jax
     import jax.numpy as jnp
     import numpy as np
 
     from repro.core import lowering
     from repro.core.engine import FusedEngine
     from repro.core.ir import Node
+    from repro.launch.mesh import make_mesh
 
     rng = np.random.default_rng(31)
-    mesh = jax.make_mesh((1,), ("stage",))
+    mesh = make_mesh((1,), ("stage",))
 
     def mlp(dims, bits, with_bn=True):
         g = [Node("input", "in", {"shape": (dims[0],), "bits": bits})]
@@ -301,14 +302,14 @@ def test_dryrun_cell_lowers_on_host_mesh():
         jax.devices()  # lock 8 host devices before importing dryrun
         from repro.launch import dryrun
         from repro.configs import get_reduced
-        from repro.launch.mesh import make_host_mesh, use_mesh
+        from repro.launch.mesh import make_mesh
 
-        mesh = make_host_mesh((4, 2))
+        mesh = make_mesh((4, 2))
         for arch in ["yi-9b", "granite-moe-3b-a800m", "mamba2-780m"]:
             cfg = get_reduced(arch)
             fn, args, donate, shardings, cfg, acct = dryrun.build_cell(
                 cfg, "train_4k", mesh)
-            with use_mesh(mesh):
+            with jax.set_mesh(mesh):
                 compiled = jax.jit(fn, in_shardings=shardings,
                                    donate_argnums=donate).lower(*args).compile()
             cost = compiled.cost_analysis()

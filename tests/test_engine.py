@@ -100,6 +100,32 @@ def test_fused_engine_matches_interpreter_conv(mode):
     assert [node.op for node in engine.graph] == ["input", "conv_mvu"]
 
 
+@pytest.mark.parametrize("mode", ["standard", "binary"])
+@pytest.mark.parametrize("bits", [2, 8])
+def test_engine_exact_at_act_bits(mode, bits):
+    """8-bit activations (levels up to 255) do not fit the MXU's int8: the
+    engine must stay bit-exact with the ``backend="xla"`` (ref.py) build,
+    and only streams proven to fit int8 are narrowed."""
+    rng = np.random.default_rng(17)
+    dims = [48, 40, 24, 8]
+    g = _mlp_graph(rng, dims, bits)
+    graphs = {be: lowering.finalize(lowering.lower_to_mvu(
+        g, mode=mode, weight_bits=4, act_bits=bits, backend=be))
+        for be in ("pallas", "xla")}
+    x = jnp.asarray(rng.integers(0, 2**bits, (9, dims[0])), jnp.int32)
+    engine = FusedEngine(graphs["pallas"])
+    want = np.asarray(FusedEngine(graphs["xla"])(x))
+    np.testing.assert_array_equal(np.asarray(engine(x)), want)
+    np.testing.assert_array_equal(
+        np.asarray(dataflow.execute(graphs["pallas"], x)), want)
+    mvus = {n.name for n in engine.graph if n.op == "mvu"}
+    assert dataflow.int8_inputs(engine.graph) == (mvus if bits == 2 else set())
+    if bits == 8:  # the hidden activations really leave the int8 range
+        env = dataflow.trace(engine.graph, x)
+        assert max(int(np.asarray(env[n]).max()) for n in mvus
+                   if n != "fc2.mvu") > 127
+
+
 def test_microbatch_streaming_invariance():
     """Any microbatch split (including ragged last chunk) gives the same
     result as a single full-batch pass."""
@@ -237,6 +263,7 @@ def test_engine_pipeline_multidevice_matches_single():
         from repro.core import lowering
         from repro.core.engine import FusedEngine
         from repro.core.ir import Node
+        from repro.launch.mesh import make_mesh
 
         rng = np.random.default_rng(0)
         d, L, bits = 32, 4, 2
@@ -255,7 +282,7 @@ def test_engine_pipeline_multidevice_matches_single():
             lowering.lower_to_mvu(g, mode="standard", weight_bits=4, act_bits=bits))
         eng = FusedEngine(fin)
         x = jnp.asarray(rng.integers(0, 2**bits, (8, 4, d)), jnp.int32)
-        run = eng.as_pipeline(jax.make_mesh((4,), ("stage",)))
+        run = eng.as_pipeline(make_mesh((4,), ("stage",)))
         got = np.asarray(run(x))
         want = np.asarray(eng(x.reshape(32, d))).reshape(8, 4, d)
         assert np.array_equal(got, want)

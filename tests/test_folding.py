@@ -61,17 +61,54 @@ def test_illegal_folding_raises():
 
 
 def test_to_tpu_blocks_xnor_words():
-    blocks = f.to_tpu_blocks(f.Folding(64, 64), "xnor")
-    assert blocks["block_kw"] == 2  # 64 synapses = 2 packed words
-    blocks = f.to_tpu_blocks(f.Folding(64, 64), "standard")
+    # 64 synapses = 2 packed words; K = 64 bits is 2 words, so the word
+    # step spans the whole (8-padded) packed dim
+    blocks = f.to_tpu_blocks(f.Folding(64, 64), "xnor", n=64, k=64)
+    assert blocks["block_kw"] == 8 and blocks["block_n"] == 64
+    # at K = 8192 (256 words) the 2-word step rounds up to 128 lanes
+    blocks = f.to_tpu_blocks(f.Folding(64, 64), "xnor", n=64, k=8192)
+    assert blocks["block_kw"] == 128
+    blocks = f.to_tpu_blocks(f.Folding(64, 64), "standard", n=64, k=64)
     assert blocks["block_k"] == 64 and blocks["block_n"] == 64
 
 
 def test_block_candidates_contains_heuristic_and_clamps():
     n, k = 24, 96
     cands = f.block_candidates(n, k, "standard")
-    heur = f.to_tpu_blocks(f.choose_folding(n, k), "standard")
+    heur = f.to_tpu_blocks(f.choose_folding(n, k), "standard", n=n, k=k)
     assert heur in cands
     assert all(c["block_n"] >= 8 and c["block_k"] >= 8 for c in cands)
     xc = f.block_candidates(24, 96, "xnor")
     assert all("block_kw" in c and c["block_kw"] >= 1 for c in xc)
+
+
+@pytest.mark.parametrize("n,k,mode,packed", [
+    (64, 600, "standard", False), (1, 64, "standard", False),
+    (512, 2048, "binary", False), (512, 4608, "xnor", False),
+    (10, 512, "xnor", False), (64, 600, "binary", True),
+    (64, 600, "standard", True),
+])
+def test_block_candidates_are_chip_legal_and_unique(n, k, mode, packed):
+    """Every candidate is the tile the kernels run (a fixed point of
+    legal_blocks): block_n a multiple of 128 or the whole 8-padded N, the
+    K step a multiple of 128 lanes or the whole K; no tile twice."""
+    cands = f.block_candidates(n, k, mode, packed=packed)
+    assert cands
+    assert all(f.legal_blocks(c, n, k, mode, packed=packed) == c for c in cands)
+    assert len({tuple(sorted(c.items())) for c in cands}) == len(cands)
+    full_n = -(-n // 8) * 8
+    for c in cands:
+        assert c["block_n"] % 128 == 0 or c["block_n"] == full_n
+        assert c["block_m"] % 8 == 0
+
+
+def test_folding_cycles_unchanged_by_tile_rounding():
+    """Table 6/7: the cycle model stays on the FINN (PE, SIMD) values even
+    though the kernels round PE=64 x SIMD=50 up to a 128-lane K step."""
+    from repro.configs import nid_mlp
+
+    cycles = [f.Folding(pe, simd).cycles(n, k)
+              for k, n, pe, simd in nid_mlp.LAYERS]
+    assert cycles == [12, 8, 8, 8]
+    blocks = f.to_tpu_blocks(f.Folding(64, 50), "standard", n=64, k=600)
+    assert blocks == {"block_m": 128, "block_n": 64, "block_k": 128}

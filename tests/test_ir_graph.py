@@ -234,3 +234,31 @@ def test_schedule_reports_branch_joins():
     assert sched.summary()["joins"][0]["name"] == "res"
     # critical path: latency is the longest path, not the sum of all stages
     assert sched.latency_cycles < sum(s.cycles for s in sched.stages)
+
+
+@pytest.mark.parametrize("bits,op,scales,narrow", [
+    (2, "add", (1, 1), True),     # [0,3] + [-3,3]: fits int8
+    (7, "add", (1, 1), False),    # [0,127] + [-127,127] reaches 254
+    (6, "mul", (1, 1), False),    # 63 * 63 leaves int8
+    (2, "add", (64, 1), False),   # an alignment scale of 64 leaves int8
+    (7, "sub", (1, 1), False),    # 127 - (-127) leaves int8
+])
+def test_int8_inputs_proves_ranges_through_joins(bits, op, scales, narrow):
+    """``dataflow.int8_inputs`` narrows an MVU's input only where the static
+    range (input bits of either sign, quant_act grids, eltwise with integer
+    scales) fits int8; ``head`` reads the join."""
+    from repro.core.mvu import MVUConfig
+
+    def mvu(name, src):
+        return Node("mvu", name, {"config": MVUConfig(16, 16)}, {}, inputs=(src,))
+
+    g = Graph([
+        _input(bits=bits),
+        mvu("fc0", "in"),
+        Node("quant_act", "a0", {"bits": bits}, inputs=("fc0",)),
+        Node(op, "join", {"scales": scales}, inputs=("a0", "in")),
+        mvu("head", "join"),
+    ])
+    got = dataflow.int8_inputs(g)
+    assert ("fc0" in got) == (bits <= 7)
+    assert ("head" in got) == narrow

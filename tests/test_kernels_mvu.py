@@ -136,3 +136,92 @@ def test_accumulator_width_no_overflow():
     got = ops.mvu(jnp.asarray(a), jnp.asarray(w), "standard",
                   block_m=8, block_n=8, block_k=256)
     assert int(np.asarray(got)[0, 0]) == 49 * k
+
+
+# Activations wider than int8: an 8-bit unsigned grid (act_bits=8, values up
+# to 255), full int16, and large int32.  The MXU multiplies int8, so these
+# run once per base-256 digit and must still equal the oracle exactly.
+WIDE = {
+    "uint8": (0, 256, np.uint8),
+    "int16": (-2**15, 2**15, np.int16),
+    "int32": (-2**20, 2**20, np.int32),
+}
+WIDE_PATHS = ["standard", "binary", "binary_packed", "int2_packed",
+              "conv_standard", "conv_binary", "conv_xnor"]
+PACKED_MODE = {"binary_packed": "binary", "int2_packed": "standard"}
+
+
+def _thresholds_for(acc, n_thresh, seed):
+    """Ascending per-column thresholds spread over the accumulator's range,
+    so every output level is reachable."""
+    qs = np.quantile(acc.reshape(-1, acc.shape[-1]), np.linspace(0.1, 0.9, n_thresh),
+                     axis=0).T.astype(np.int64)
+    jitter = np.random.default_rng(seed).integers(-2, 3, qs.shape)
+    return np.sort(qs + jitter, axis=1).astype(np.int32)
+
+
+@pytest.mark.parametrize("dtype", sorted(WIDE))
+@pytest.mark.parametrize("path", WIDE_PATHS)
+def test_wide_activations_match_oracle(path, dtype):
+    from repro.kernels import mvu_packed
+
+    lo, hi, npdt = WIDE[dtype]
+    if path.startswith("conv"):
+        mode = path.split("_")[1]
+        x = _rand((2, 6, 7, 5), lo, hi, 21, npdt)
+        if mode == "xnor" and dtype == "uint8":
+            x = _rand((2, 6, 7, 5), 0, 2, 21, npdt)  # the usual {0,1} bits
+        n, kd = 11, 3
+        w = (_rand((n, kd * kd * 5), -8, 8, 22) if mode == "standard"
+             else _rand((n, kd * kd * 5), 0, 2, 22))
+        kw = dict(kernel=kd, stride=1, pad=1, mode=mode)
+        acc = np.asarray(ref.conv_mvu_ref(jnp.asarray(x), jnp.asarray(w), **kw))
+        t = jnp.asarray(_thresholds_for(acc, 7, 23))
+        want = ref.conv_mvu_ref(jnp.asarray(x), jnp.asarray(w), thresholds=t, **kw)
+        wk = (packing.pack_bits(jnp.asarray(w, jnp.int32)) if mode == "xnor"
+              else jnp.asarray(w))
+        got = ops.conv_mvu(jnp.asarray(x), wk, thresholds=t, block_m=16,
+                           block_n=8, k_bits=w.shape[1], **kw)
+    else:
+        m, n, k = 9, 13, 70
+        a = jnp.asarray(_rand((m, k), lo, hi, 24, npdt))
+        if path in ("standard", "int2_packed"):
+            wlo, whi = (-2, 2) if path == "int2_packed" else (-8, 8)
+            w = jnp.asarray(_rand((n, k), wlo, whi, 25))
+            oracle = ref.mvu_int_ref
+        else:
+            w = jnp.asarray(_rand((n, k), 0, 2, 25))
+            oracle = ref.mvu_binary_ref
+        t = jnp.asarray(_thresholds_for(np.asarray(oracle(a, w)), 5, 26))
+        want = oracle(a, w, t)
+        blocks = dict(block_m=8, block_n=8, block_k=32, block_kw=1)
+        if path in PACKED_MODE:
+            mode = PACKED_MODE[path]
+            got = ops.mvu(a, mvu_packed.pack_mvu_weights(w, mode), mode,
+                          k_bits=k, thresholds=t, packed=True, **blocks)
+        else:
+            got = ops.mvu(a, w, path, thresholds=t, **blocks)
+    want = np.asarray(want)
+    assert want.min() < want.max()  # every case reaches several levels
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("epilogue", ["raw", "scale"])
+@pytest.mark.parametrize("path", ["standard", "conv_standard"])
+def test_wide_activations_raw_and_scale_epilogues(path, epilogue):
+    s = np.random.default_rng(27).uniform(0.01, 2.0, (11,)).astype(np.float32)
+    scale = jnp.asarray(s) if epilogue == "scale" else None
+    if path == "standard":
+        a = jnp.asarray(_rand((9, 70), 0, 256, 28, np.int32))
+        w = jnp.asarray(_rand((11, 70), -8, 8, 29))
+        want = ref.mvu_int_ref(a, w, out_scale=scale)
+        got = ops.mvu(a, w, "standard", out_scale=scale,
+                      block_m=8, block_n=8, block_k=32)
+    else:
+        x = jnp.asarray(_rand((2, 6, 6, 4), 0, 256, 30, np.int32))
+        w = jnp.asarray(_rand((11, 36), -8, 8, 31))
+        kw = dict(kernel=3, stride=2, pad=1, mode="standard", out_scale=scale)
+        want = ref.conv_mvu_ref(x, w, **kw)
+        got = ops.conv_mvu(x, w, block_m=8, block_n=8, **kw)
+    assert np.asarray(got).dtype == np.asarray(want).dtype
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

@@ -190,20 +190,23 @@ def test_pack_never_ignores_cached_packed_entry():
 def test_tune_node_selects_packed_with_stub_timer():
     """With a timer that always reports the challenger 3x faster, the
     winning entry is a bit-exact packed candidate (the packed axis is in
-    the searched space, not bolted on after)."""
+    the searched space, not bolted on after).  At K = 4096 the packed
+    word step covers all of K in one 128-word tile while the int8 K step
+    stops at 512 lanes, so the cycle model measures the packed schedules
+    first and the first challenger wins."""
     fin = lowering.finalize(lowering.lower_to_mvu(
-        _mlp_graph((64, 16)), mode="binary", weight_bits=1, act_bits=2))
+        _mlp_graph((4096, 16)), mode="binary", weight_bits=1, act_bits=2))
     node = next(n for n in fin if n.op == "mvu")
 
     def fast_challenger(base_fn, fn, x, reps=3):
         return 1.0, 1.0 / 3.0, 3.0
 
-    entry = autotune.tune_node(node, (64,), timer=fast_challenger,
+    entry = autotune.tune_node(node, (4096,), timer=fast_challenger,
                                sample_m=16, max_measure=16)
     assert entry.get("packed") is True
     assert entry["speedup"] == pytest.approx(3.0)
     # and the policy switch removes packed candidates from the search
-    entry = autotune.tune_node(node, (64,), timer=fast_challenger,
+    entry = autotune.tune_node(node, (4096,), timer=fast_challenger,
                                sample_m=16, max_measure=16,
                                allow_packed=False)
     assert not entry.get("packed")

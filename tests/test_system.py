@@ -29,12 +29,12 @@ def _batches(cfg, n, b=4, s=32, seed=0):
 def test_train_crash_resume_equivalence():
     """Training interrupted at step 4 and resumed from checkpoint reaches the
     exact same loss trajectory as the uninterrupted run."""
-    from repro.launch.mesh import make_host_mesh
+    from repro.launch.mesh import make_mesh
     from repro.launch.train import train_loop
     from repro.optim import adamw
 
     model, cfg = _tiny_model()
-    mesh = make_host_mesh((1, 1))
+    mesh = make_mesh((1, 1))
     opt = adamw.AdamWConfig(lr=1e-3, warmup_steps=2, total_steps=8)
     data = _batches(cfg, 20)
 

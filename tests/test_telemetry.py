@@ -426,6 +426,7 @@ def test_pipeline_traced_multidevice_occupancy():
         from repro.core import lowering
         from repro.core.engine import FusedEngine
         from repro.core.ir import Node
+        from repro.launch.mesh import make_mesh
         from repro.telemetry import Tracer
 
         rng = np.random.default_rng(0)
@@ -442,7 +443,7 @@ def test_pipeline_traced_multidevice_occupancy():
         eng = FusedEngine(fin)
         x = jnp.asarray(rng.integers(0, 2**bits, (8, 4, d)), jnp.int32)
         tr = Tracer()
-        run = eng.as_pipeline(jax.make_mesh((4,), ("stage",)), tracer=tr)
+        run = eng.as_pipeline(make_mesh((4,), ("stage",)), tracer=tr)
         got = np.asarray(run(x))
         want = np.asarray(eng(x.reshape(32, d))).reshape(8, 4, d)
         assert np.array_equal(got, want)
