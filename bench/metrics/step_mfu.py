@@ -1,0 +1,3 @@
+"""The whole step's share of the chip's int8 peak, in the CNV cell."""
+
+from bench.roofline.share import step_mfu as read  # noqa: F401
