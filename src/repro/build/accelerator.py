@@ -71,12 +71,6 @@ class Accelerator:
         """Non-blocking engine submit (see ``FusedEngine.dispatch``)."""
         return self.engine.dispatch(x, params=params, tracer=tracer)
 
-    def profile(self, x, tracer, *, drift=None):
-        """Traced per-node eager re-execution (``FusedEngine.profile``):
-        bit-exact with ``acc(x)``, one span per node, optionally feeding a
-        :class:`~repro.telemetry.DriftMonitor`."""
-        return self.engine.profile(x, tracer, drift=drift)
-
     def drift_monitor(self, **kwargs):
         """A :class:`~repro.telemetry.DriftMonitor` primed with this
         build's per-stage predicted intervals (stage cycles x the

@@ -159,11 +159,15 @@ class FusedEngine:
         # traced once under jit: the env is a compile-time dict of traced
         # values, so fan-out reuses one stream and joins consume both arms
         # inside the same fused program -- no interpreter overhead survives.
+        # Each node runs under its name scope: it lands in the op_name
+        # metadata of the node's ops, so in a device trace, and changes no
+        # instruction of the program.
         env: dict = {}
         for name, ins, p, fn in zip(self._names, self._in_names,
                                     params, self._fns):
             args = (x,) if not ins else tuple(env[s] for s in ins)
-            env[name] = fn(p, *args)
+            with jax.named_scope(name):
+                env[name] = fn(p, *args)
         return env[self._out_name]
 
     def _stream(self, params, x, n_micro: int):
@@ -193,8 +197,9 @@ class FusedEngine:
 
         ``tracer`` (a :class:`repro.telemetry.Tracer`) records the host-side
         enqueue as an ``engine.dispatch`` span -- the duration is submit
-        cost, not compute (the call does not block); per-node compute spans
-        come from :meth:`profile`.
+        cost, not compute (the call does not block).  Per-node device time
+        is in a profiler trace: every device op carries its node's name
+        scope (:meth:`_chain`).
         """
         plan = self.plan(int(x.shape[0]))
         params = self.params if params is None else params
@@ -206,54 +211,6 @@ class FusedEngine:
                          interval_cycles=plan.interval_cycles):
             out = self._jit(params, x, plan.n_micro)
         return out, plan
-
-    def profile(self, x: jax.Array, tracer, *, drift=None
-                ) -> tuple[jax.Array, StreamPlan]:
-        """Instrumented run: per-node, per-microbatch duration spans.
-
-        The jit'd :meth:`dispatch` path is one fused program -- XLA leaves
-        no per-node boundary to time -- so profiling re-runs the SAME node
-        runners (``dataflow.node_runner``, the definitions the fused chain
-        traced) eagerly per microbatch, blocking after each node.  Every op
-        is per-sample, so the output is bit-exact with :meth:`dispatch`;
-        only the timing differs (each node pays its own dispatch, which is
-        the point).  Span tree::
-
-            engine.profile
-              micro0
-                <node name>   one span per graph node, cat="node"
-              micro1
-                ...
-
-        ``drift`` (a :class:`repro.telemetry.DriftMonitor`) receives each
-        node span duration keyed by node name -- with predictions from
-        ``DriftMonitor.from_schedule(engine.schedule, s_per_cycle)`` this
-        compares measured per-node intervals against the calibrated cycle
-        model online.
-        """
-        b = int(x.shape[0])
-        plan = self.plan(b)
-        mb = plan.microbatch
-        pad = plan.n_micro * mb - b
-        xp = jnp.pad(x, [(0, pad)] + [(0, 0)] * (x.ndim - 1)) if pad else x
-        xs = xp.reshape(plan.n_micro, mb, *x.shape[1:])
-        outs = []
-        with tracer.span("engine.profile", cat="engine", batch=b,
-                         n_micro=plan.n_micro, microbatch=mb):
-            for m in range(plan.n_micro):
-                with tracer.span(f"micro{m}", cat="engine"):
-                    env: dict = {}
-                    for name, ins, p, fn in zip(self._names, self._in_names,
-                                                self.params, self._fns):
-                        with tracer.span(name, cat="node", micro=m) as sp:
-                            args = ((xs[m],) if not ins
-                                    else tuple(env[s] for s in ins))
-                            env[name] = jax.block_until_ready(fn(p, *args))
-                        if drift is not None:
-                            drift.observe(name, sp.dur)
-                    outs.append(env[self._out_name])
-        y = jnp.concatenate(outs)[:b]
-        return y, plan
 
     def __call__(self, x: jax.Array) -> jax.Array:
         return self.dispatch(x)[0]

@@ -21,6 +21,13 @@ flat.  ``to_chrome()``/``save()`` export the Chrome trace-event JSON
 format, viewable in Perfetto (https://ui.perfetto.dev) or
 ``chrome://tracing``.
 
+Every code-region span and every instant is also mirrored into the JAX
+profiler as a ``jax.profiler.TraceAnnotation`` of the same name (args at
+entry), so a profiler trace (``.xplane.pb``) shows the program's spans on
+the host timeline, on the device ops' clock.  While no profiler records,
+no annotation is made.  ``emit_span`` stays ring-only: a reconstructed
+schedule is not a code region.
+
 Zero overhead when disabled is a hard requirement: components hold
 ``tracer = None`` and guard every emission with ``if tracer is not None``
 -- one attribute load and an identity test, nothing allocated, nothing
@@ -35,6 +42,8 @@ import os
 import threading
 import time
 
+from jax.profiler import TraceAnnotation
+
 
 class SpanHandle:
     """Context manager for one open duration span.
@@ -48,7 +57,8 @@ class SpanHandle:
             sp.args["replica"] = pending.replica.index
     """
 
-    __slots__ = ("_tracer", "name", "cat", "args", "t0", "t1", "depth")
+    __slots__ = ("_tracer", "name", "cat", "args", "t0", "t1", "depth",
+                 "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, args: dict):
         self._tracer = tracer
@@ -60,11 +70,17 @@ class SpanHandle:
         stack = self._tracer._stack()
         self.depth = len(stack)
         stack.append(self)
+        self._annotation = None
+        if TraceAnnotation.is_enabled():  # a profiler is recording
+            self._annotation = TraceAnnotation(self.name, **self.args)
+            self._annotation.__enter__()
         self.t0 = self._tracer.clock()
         return self
 
     def __exit__(self, *exc) -> None:
         t1 = self.t1 = self._tracer.clock()
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
         stack = self._tracer._stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -154,7 +170,11 @@ class Tracer:
                     "args": args})
 
     def instant(self, name: str, cat: str = "", **args) -> None:
-        """Point annotation at the current clock (a retry, a quarantine)."""
+        """Point annotation at the current clock (a retry, a quarantine);
+        a zero-length annotation in the profiler."""
+        if TraceAnnotation.is_enabled():
+            with TraceAnnotation(name, **args):
+                pass
         self._emit({"ph": "i", "name": name, "cat": cat, "t": self.clock(),
                     "tid": threading.get_ident(), "args": args})
 

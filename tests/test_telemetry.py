@@ -2,13 +2,17 @@
 Chrome export), log-bucketed histograms + windowed rates + Prometheus
 exposition, the drift monitor (censored observations, latched flags),
 thread-safe ServingMetrics, engine/pipeline instrumentation invariants
-(traced == untraced bit-exactness, per-node spans sum within the enclosing
-span), and the regression gate's None tolerance."""
+(traced == untraced bit-exactness, every node's name scope on its compiled
+ops and nothing else changed), the mirror of spans and instants into the
+JAX profiler, and the regression gate's None tolerance."""
 
+import contextlib
+import glob
 import importlib.util
 import json
 import math
 import os
+import re
 import subprocess
 import sys
 import textwrap
@@ -16,9 +20,13 @@ import threading
 
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
+from jax.profiler import ProfileData
 
+from repro.core import lowering
 from repro.core.engine import FusedEngine
+from repro.core.ir import Node
 from repro.distributed.pipeline import emit_schedule_spans, pipeline_occupancy
 from repro.serving import ContinuousBatcher, ServingMetrics
 from repro.telemetry import (
@@ -131,6 +139,81 @@ def test_tracer_summary_aggregates_per_name():
     assert s["spans"]["step"]["count"] == 3
     assert s["events"]["X"] == 3
     assert s["dropped"] == 0
+
+
+# --------------------------------------------------------- profiler mirror
+def _profiled(tmp_path, fn):
+    """Run ``fn`` under the JAX profiler; the host plane's events as
+    ``(name, start_ns, end_ns, {stat: str})``, in start order."""
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    events = [(ev.name, ev.start_ns, ev.end_ns,
+               {k: str(v) for k, v in ev.stats})
+              for plane in ProfileData.from_file(path).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for ev in line.events]
+    return sorted(events, key=lambda e: (e[1], -e[2]))
+
+
+def _nested_spans(tr):
+    with tr.span("engine.dispatch", cat="engine", batch=4, mode="xnor"):
+        with tr.span("dispatch", cat="serving", replica=1):
+            tr.instant("quarantine", cat="health", replica=1, reason="slow")
+
+
+def test_spans_and_instants_mirror_into_the_profiler(tmp_path):
+    events = _profiled(tmp_path, lambda: _nested_spans(Tracer()))
+    mine = {e[0]: e for e in events
+            if e[0] in ("engine.dispatch", "dispatch", "quarantine")}
+    assert set(mine) == {"engine.dispatch", "dispatch", "quarantine"}
+    outer, inner, instant = (mine["engine.dispatch"], mine["dispatch"],
+                             mine["quarantine"])
+    assert outer[3] == {"batch": "4", "mode": "xnor"}
+    assert inner[3] == {"replica": "1"}
+    assert instant[3] == {"replica": "1", "reason": "slow"}
+    # nested as in the code, the instant inside the innermost span
+    assert outer[1] <= inner[1] <= instant[1] <= instant[2] <= inner[2] <= outer[2]
+    assert instant[2] - instant[1] < inner[2] - inner[1]
+
+
+def test_the_ring_is_the_same_with_and_without_a_profiler(tmp_path):
+    def ring(tr):
+        return [{k: v for k, v in ev.items() if k not in ("t", "t0", "t1")}
+                for ev in tr.events()]
+
+    plain, recorded = Tracer(), Tracer()
+    _nested_spans(plain)
+    _profiled(tmp_path, lambda: _nested_spans(recorded))
+    assert ring(recorded) == ring(plain)
+    assert [e["name"] for e in recorded.events()] == [
+        "quarantine", "dispatch", "engine.dispatch"]
+
+
+@pytest.mark.parametrize("traced", [False, True])
+def test_engine_and_batcher_annotate_only_with_a_tracer(tmp_path, traced):
+    engine = FusedEngine(_mlp_graph())
+    xs = _samples(6)
+    tr = Tracer() if traced else None
+    batcher = ContinuousBatcher(engine, batch_buckets=(1, 4), tracer=tr)
+    batcher.warmup()
+    jax.block_until_ready(engine.dispatch(jnp.asarray(xs))[0])
+
+    def run():
+        jax.block_until_ready(engine.dispatch(jnp.asarray(xs), tracer=tr)[0])
+        batcher.submit_batch(xs)
+        batcher.drain()
+
+    names = {e[0] for e in _profiled(tmp_path, run)}
+    program = {"engine.dispatch", "dispatch", "resolve"}
+    if traced:
+        assert program <= names
+    else:
+        assert not program & names
+        assert not [n for n in names if n.startswith(("queue", "request"))]
 
 
 # ---------------------------------------------------------------- histogram
@@ -317,27 +400,85 @@ def test_serving_metrics_percentiles_and_prometheus():
 
 
 # ------------------------------------------------- engine instrumentation
-def test_engine_profile_bit_exact_and_node_spans_nest():
-    engine = FusedEngine(_mlp_graph(), microbatches=2)
-    x = jnp.asarray(_samples(6))
-    want = np.asarray(engine(x))
-    tr = Tracer()
-    drift = DriftMonitor.from_schedule(engine.schedule, 1e-8)
-    got, plan = engine.profile(x, tr, drift=drift)
-    np.testing.assert_array_equal(np.asarray(got), want)
+def _conv_graph(bits=2, seed=11):
+    rng = np.random.default_rng(seed)
+    w0 = rng.normal(0, 0.5, (3, 3, 3, 8)).astype(np.float32)
+    w1 = rng.normal(0, 0.5, (4, 3 * 3 * 8)).astype(np.float32)
+    g = [Node("input", "in", {"shape": (8, 8, 3), "bits": bits}),
+         Node("conv", "c0", {"kernel": 3, "stride": 1, "pad": 0},
+              {"w": jnp.asarray(w0)}),
+         Node("quant_act", "act0", {"bits": bits, "act_scale": 1.0}),
+         Node("maxpool", "pool0", {"size": 2}),
+         Node("flatten", "flat", {}),
+         Node("linear", "fc", {}, {"w": jnp.asarray(w1)})]
+    return lowering.finalize(lowering.lower_to_mvu(
+        g, mode="standard", weight_bits=4, act_bits=bits))
 
-    spans = tr.spans()
-    assert_no_overlap_within_thread(spans)
-    outer = tr.spans(name="engine.profile")[0]
-    node_spans = tr.spans(cat="node")
-    assert len(node_spans) == plan.n_micro * len(engine.graph)
-    # per-node spans sum to no more than the enclosing profile span
-    assert sum(s["dur"] for s in node_spans) <= outer["dur"] + 1e-9
-    for s in node_spans:
-        assert outer["t0"] <= s["t0"] and s["t1"] <= outer["t1"]
-    # every scheduled stage's observation reached the drift monitor (the
-    # input node has no schedule stage, so no prediction: dropped)
-    assert set(drift.status()["keys"]) == {s.name for s in engine.schedule.stages}
+
+def _conv_samples(n, bits=2, seed=0):
+    return np.random.default_rng(seed).integers(
+        0, 2**bits, (n, 8, 8, 3)).astype(np.int32)
+
+
+ENGINE_CASES = {"mlp": (_mlp_graph, _samples), "conv": (_conv_graph, _conv_samples)}
+# what the compiled HLO text says about the source: each op's metadata and
+# the tables of files, functions and stack frames its ids point into
+_METADATA = re.compile(r", metadata=\{[^}]*\}")
+_SOURCE_TABLES = re.compile(
+    r"^(FileNames|FunctionNames|FileLocations|StackFrames)\n(?:.+\n)*", re.M)
+
+
+def _without_metadata(compiled_text):
+    return _SOURCE_TABLES.sub("", _METADATA.sub("", compiled_text))
+
+
+def _compiled_text(engine, x, n_micro):
+    return engine._jit.lower(engine.params, x, n_micro).compile().as_text()
+
+
+def _op_names(compiled_text):
+    return re.findall(r'op_name="([^"]*)"', compiled_text)
+
+
+def _scopes(op_names, names):
+    """The node names that occur as a path component of some op name."""
+    return {part for op in op_names for part in op.split("/") if part in names}
+
+
+@pytest.mark.parametrize("case", sorted(ENGINE_CASES))
+def test_engine_ops_carry_their_node_scope(case):
+    make_graph, make_x = ENGINE_CASES[case]
+    engine = FusedEngine(make_graph(), microbatches=2)
+    compute = {n.name for n in engine.graph if n.op != "input"}
+    lowered = engine._jit.lower(engine.params, jnp.asarray(make_x(6)), 2)
+    # every node, a flatten's lone reshape included, names its ops
+    locations = re.findall(r'loc\("([^"]*)"', lowered.as_text(debug_info=True))
+    assert _scopes(locations, compute) == compute
+    # after compilation each kernel's ops (interpret mode here; a
+    # tpu_custom_call on the chip, see test_tpu_compile) keep their node
+    ops = _op_names(lowered.compile().as_text())
+    kernel_ops = [op for op in ops if "_pallas)" in op]
+    assert kernel_ops
+    assert all(_scopes([op], compute) for op in kernel_ops)
+    kernels = {n.name for n in engine.graph if n.op in ("mvu", "conv_mvu")}
+    assert kernels and kernels <= _scopes(ops, compute)
+
+
+@pytest.mark.parametrize("case", sorted(ENGINE_CASES))
+def test_node_scopes_change_only_metadata(case, monkeypatch):
+    make_graph, make_x = ENGINE_CASES[case]
+    x = jnp.asarray(make_x(6))
+    scoped = FusedEngine(make_graph(), microbatches=2)
+    with_scopes = _compiled_text(scoped, x, 2)
+    y_scoped = np.asarray(scoped(x))
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = FusedEngine(make_graph(), microbatches=2)
+    without = _compiled_text(plain, x, 2)
+    compute = {n.name for n in plain.graph if n.op != "input"}
+    assert not _scopes(_op_names(without), compute)
+    assert _without_metadata(with_scopes) == _without_metadata(without)
+    np.testing.assert_array_equal(y_scoped, np.asarray(plain(x)))
 
 
 def test_engine_dispatch_traced_matches_untraced():

@@ -17,6 +17,7 @@ this file.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -139,7 +140,13 @@ def test_nid_engine_step_compiles(one_chip, monkeypatch):
         engine.params)
     x = jax.ShapeDtypeStruct((batch, 600), jnp.int32, sharding=one_chip)
     compiled = engine._jit.lower(params, x, engine.plan(batch).n_micro).compile()
-    assert ops.tpu_kernel_names(compiled.as_text()) == ["mvu_int"] * 4
+    text = compiled.as_text()
+    assert ops.tpu_kernel_names(text) == ["mvu_int"] * 4
+    # each kernel carries its node's name scope, which a device trace shows
+    kernel_scopes = re.findall(
+        r'custom_call_target="tpu_custom_call".*?op_name="[^"]*?/'
+        r'closed_call/([^/"]+)/', text)
+    assert kernel_scopes == ["fc0.mvu", "fc1.mvu", "fc2.mvu", "fc3.mvu"]
 
 
 @pytest.mark.parametrize("kind,dtype,passes", [
