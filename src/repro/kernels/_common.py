@@ -7,7 +7,13 @@ datapaths; only the inner dot-product step differs:
     A block (bm, bk) @ index (m, k)      # input buffer, one SF chunk per step
     W block (bn, bk) @ index (n, k)      # weight stream (PE memories)
     acc scratch (bm, bn) int32 in VMEM   # PE accumulators
+    T block (T, bn) @ index (0, n)       # threshold table, one row per level
     epilogue at k == SF-1                # thresholds / scale / raw acc
+
+The multi-threshold epilogue broadcasts each (1, bn) threshold row down the
+sublanes of the (bm, bn) accumulator tile and sums the hits, so channels
+stay on lanes and each vector compare covers a whole (8, 128) tile of
+outputs.
 
 PE = bn rows in parallel, SIMD = bk synapses per grid step (x32 for the
 bit-packed datapath). II = 1 grid step.
@@ -112,7 +118,7 @@ def by_int8_digits(raw, a: jax.Array, thresholds, out_scale, offset=None):
     acc = sum(raw(d) * (256**p) for p, d in enumerate(digits))
     if offset is not None:
         acc = acc - offset * (sum(256**p for p in range(len(digits))) - 1)
-    t = None if thresholds is None else thresholds.astype(jnp.int32)
+    t = None if thresholds is None else thresholds.astype(jnp.int32).T
     s = None if out_scale is None else out_scale.astype(jnp.float32)
     return epilogue_value(acc, t, s)
 
@@ -132,14 +138,53 @@ def swar_popcount(x: jax.Array) -> jax.Array:
 
 
 def epilogue_value(acc, t_ref, s_ref):
-    """MVTU epilogue as a value: thresholds > scale > raw accumulator."""
+    """MVTU epilogue as a value: thresholds > scale > raw accumulator.
+
+    ``t_ref`` is the (T, bn) threshold table: a kernel's VMEM block, or the
+    whole (T, N) array where the epilogue runs in XLA.
+    """
     if t_ref is not None:
-        # act = sum_t (acc >= T[c, t]) -- the multi-threshold unit.
-        thr = t_ref[...]  # (bn, T) int32
-        return jnp.sum(acc[:, :, None] >= thr[None, :, :], axis=-1, dtype=jnp.int32)
+        return _multi_threshold(acc, t_ref)
     if s_ref is not None:
         return acc.astype(jnp.float32) * s_ref[...].reshape(1, -1)
     return acc
+
+
+def _multi_threshold(acc, t_ref):
+    """act = sum_t (acc >= T[t, :]) -- the multi-threshold unit.
+
+    Threshold rows compare as a (rows, M, bn) value whose row axis leads:
+    each row is broadcast down the sublanes of the whole (M, bn) tile, the
+    channels stay on lanes, and the sum over rows is plain vector adds.  A
+    table taller than one sublane group (8-bit activations have 255 rows)
+    loops over 8-row groups, so the kernel's live values, body and compile
+    time stay bounded.  One unrolled ``acc >= T[t:t+1, :]`` per row gives
+    the chip the same code but compiles far slower on XLA's CPU backend,
+    where interpret mode runs the kernels.
+    """
+    def count(rows):
+        return jnp.sum(acc[None] >= rows[:, None, :], axis=0, dtype=jnp.int32)
+
+    n_thresh = t_ref.shape[0]
+    if n_thresh <= SUBLANE:
+        return count(t_ref[...])
+    groups = n_thresh // SUBLANE
+    act = jax.lax.fori_loop(
+        0, groups,
+        lambda g, act: act + count(_row_group(t_ref, g)),
+        jnp.zeros(acc.shape, jnp.int32))
+    if n_thresh % SUBLANE:
+        act = act + count(t_ref[groups * SUBLANE:, :])
+    return act
+
+
+def _row_group(t_ref, g):
+    """Rows ``[8g, 8g + 8)`` (traced ``g``) of a kernel's table ref or of
+    an XLA table."""
+    start = g * SUBLANE
+    if isinstance(jax.typeof(t_ref), jax.ref.AbstractRef):
+        return t_ref[pl.ds(pl.multiple_of(start, SUBLANE), SUBLANE), :]
+    return jax.lax.dynamic_slice_in_dim(t_ref, start, SUBLANE)
 
 
 def epilogue_write(o_ref, acc, t_ref, s_ref) -> None:
@@ -147,22 +192,27 @@ def epilogue_write(o_ref, acc, t_ref, s_ref) -> None:
     o_ref[...] = epilogue_value(acc, t_ref, s_ref)
 
 
-def epilogue_operands(thresholds, out_scale, block_n: int, index_map):
+def epilogue_operands(thresholds, out_scale, block_n: int, n_block):
     """``(in_specs, operands, out_dtype)`` for the MVTU epilogue tables.
 
-    Thresholds (N, T) int32 give int32 activations; a scale (N,) gives the
-    float32 dequantized output; neither leaves the raw int32 accumulator.
-    Rows pad to ``block_n`` (pad scale rows are 1, never read back).
+    Thresholds (N, T) int32 give int32 activations; they go in transposed,
+    a (T, N) table in (T, block_n) blocks, one threshold row per sublane.
+    A scale (N,) gives the float32 dequantized output, in (block_n, 1)
+    blocks; neither leaves the raw int32 accumulator.  ``n_block`` maps the
+    kernel's grid indices to its N-block index.  N pads to ``block_n``
+    (pad scale rows are 1, never read back).
     """
     if thresholds is not None and out_scale is not None:
         raise ValueError("thresholds and out_scale are mutually exclusive")
     if thresholds is not None:
-        t = pad_to(thresholds.astype(jnp.int32), 0, block_n)
-        return [pl.BlockSpec((block_n, t.shape[1]), index_map)], [t], jnp.int32
+        t = pad_to(thresholds.astype(jnp.int32).T, 1, block_n)
+        return ([pl.BlockSpec((t.shape[0], block_n),
+                              lambda *g: (0, n_block(*g)))], [t], jnp.int32)
     if out_scale is not None:
         s = pad_to(out_scale.reshape(-1, 1).astype(jnp.float32), 0, block_n,
                    value=1)
-        return [pl.BlockSpec((block_n, 1), index_map)], [s], jnp.float32
+        return ([pl.BlockSpec((block_n, 1), lambda *g: (n_block(*g), 0))],
+                [s], jnp.float32)
     return [], [], jnp.int32
 
 

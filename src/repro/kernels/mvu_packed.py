@@ -157,7 +157,7 @@ def mvu_binary_packed_pallas(
     a_p = pad_to(pad_to(a.astype(jnp.int8), 0, bm), 1, wdp * WORD_BITS)
     mp, _ = a_p.shape
     epi_specs, epi_ops, out_dtype = epilogue_operands(
-        thresholds, out_scale, bn, lambda mi, ni, ki: (ni, 0))
+        thresholds, out_scale, bn, lambda mi, ni, ki: ni)
     has_thresh, has_scale = thresholds is not None, out_scale is not None
 
     out = pl.pallas_call(
@@ -275,7 +275,7 @@ def mvu_int2_packed_pallas(
     a_p = pad_to(pad_to(a.astype(jnp.int8), 0, bm), 1, bdp * INT2_PER_BYTE)
     mp, _ = a_p.shape
     epi_specs, epi_ops, out_dtype = epilogue_operands(
-        thresholds, out_scale, bn, lambda mi, ni, ki: (ni, 0))
+        thresholds, out_scale, bn, lambda mi, ni, ki: ni)
     has_thresh, has_scale = thresholds is not None, out_scale is not None
 
     out = pl.pallas_call(

@@ -100,7 +100,7 @@ def mvu_xnor_pallas(
     mp, wdp = a_p.shape
     np_, _ = w_p.shape
     epi_specs, epi_ops, out_dtype = epilogue_operands(
-        thresholds, out_scale, bn, lambda mi, ni, ki: (ni, 0))
+        thresholds, out_scale, bn, lambda mi, ni, ki: ni)
     has_thresh, has_scale = thresholds is not None, out_scale is not None
 
     out = pl.pallas_call(

@@ -232,7 +232,7 @@ def conv_mvu_pallas(
     w_t = jnp.transpose(w_t, (1, 2, 0))
     np_ = w_t.shape[2]
     epi_specs, epi_ops, out_dtype = epilogue_operands(
-        thresholds, out_scale, bn, lambda bi, ti, ni: (ni, 0))
+        thresholds, out_scale, bn, lambda bi, ti, ni: ni)
     has_thresh, has_scale = thresholds is not None, out_scale is not None
 
     out = pl.pallas_call(

@@ -12,7 +12,7 @@ from repro.core import dataflow, lowering, swu
 from repro.core.engine import FusedEngine
 from repro.core.folding import Folding
 from repro.core.ir import Graph, Node
-from repro.kernels import ops, packing
+from repro.kernels import ops, packing, ref
 
 GRID = [(kd, st, pd) for kd in (1, 3, 5) for st in (1, 2) for pd in (0, 1, 2)]
 MODES = ("standard", "binary", "xnor")
@@ -84,19 +84,61 @@ def test_conv_mvu_kernel_matches_lax_conv(i, kd, stride, pad):
     np.testing.assert_array_equal(got.reshape(want.shape), want)
 
 
-def test_conv_mvu_kernel_threshold_epilogue():
-    """Fused kernel thresholds == materialized SWU + threshold reference."""
+@pytest.mark.parametrize("mode,n_thresh,table,n", [
+    pytest.param("standard", 3, "spread", 5, id="3-standard"),
+    pytest.param("xnor", 1, "spread", 5, id="1-xnor"),
+    pytest.param("binary", 15, "spread", 5, id="15-binary"),
+    pytest.param("standard", 255, "spread", 5, id="255-standard"),
+    pytest.param("wide", 255, "spread", 5, id="255-wide"),
+    pytest.param("xnor", 1, "ties", 130, id="1-xnor-ties-n130"),
+    pytest.param("standard", 3, "ties", 5, id="3-standard-ties"),
+    pytest.param("standard", 3, "below", 5, id="3-standard-below"),
+    pytest.param("xnor", 1, "above", 5, id="1-xnor-above"),
+])
+def test_conv_mvu_kernel_threshold_epilogue(mode, n_thresh, table, n):
+    """Fused kernel thresholds == materialized SWU + threshold reference.
+
+    int8 activations run the epilogue inside the kernel: T = 15 and 255 its
+    looped form, n = 130 two N blocks of 128, each with its own slice of
+    the table.  ``wide`` (int16 activations) runs it in XLA after one kernel
+    pass per base-256 digit."""
     rng = np.random.default_rng(3)
-    kd, st, pd, c, n = 3, 1, 1, 4, 5
+    kd, st, pd, c = 3, 1, 1, 4
     k = kd * kd * c
-    x = jnp.asarray(rng.integers(0, 4, (2, 7, 9, c)), jnp.int32)
-    w = jnp.asarray(rng.integers(-7, 8, (n, k)), jnp.int8)
-    t = jnp.asarray(np.sort(rng.integers(-30, 30, (n, 3)), axis=1), jnp.int32)
-    got = ops.conv_mvu(x, w, kernel=kd, stride=st, pad=pd, thresholds=t)
-    want = ops.conv_mvu(x, w, kernel=kd, stride=st, pad=pd, thresholds=t,
-                        backend="xla")
+    hi = {"xnor": 2, "wide": 2**15}.get(mode, 4)
+    x = jnp.asarray(rng.integers(0, hi, (2, 7, 9, c)),
+                    jnp.int16 if mode == "wide" else jnp.int8)
+    if mode in ("standard", "wide"):
+        w_rows = jnp.asarray(rng.integers(-7, 8, (n, k)), jnp.int8)
+    else:
+        w_rows = jnp.asarray(rng.integers(0, 2, (n, k)), jnp.int8)
+    kind = "standard" if mode == "wide" else mode
+    kw = dict(kernel=kd, stride=st, pad=pd, mode=kind)
+    acc = np.asarray(ref.conv_mvu_ref(x, w_rows, **kw))
+    if table == "spread":
+        t = rng.integers(acc.min(), acc.max() + 1, (n, n_thresh))
+    elif table == "ties":  # levels drawn from each channel's own outputs
+        t = acc.reshape(-1, n).T[np.arange(n)[:, None],
+                                 rng.integers(0, acc.size // n, (n, n_thresh))]
+    elif table == "below":
+        t = np.full((n, n_thresh), acc.min() - 1)
+    else:
+        t = np.full((n, n_thresh), acc.max() + 1)
+    t = jnp.asarray(np.sort(t, axis=1), jnp.int32)
+    w = (packing.pack_bits(w_rows.astype(jnp.int32)) if mode == "xnor"
+         else w_rows)
+    got = ops.conv_mvu(x, w, k_bits=k, thresholds=t, **kw)
+    want = ref.conv_mvu_ref(x, w_rows, thresholds=t, **kw)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    assert int(jnp.max(got)) <= 3 and int(jnp.min(got)) >= 0
+    np.testing.assert_array_equal(
+        np.asarray(want), (acc[..., None] >= np.asarray(t)).sum(-1))
+    want = np.asarray(want)
+    if table == "below":
+        assert (want == n_thresh).all()
+    elif table == "above":
+        assert (want == 0).all()
+    else:
+        assert want.max() <= n_thresh and want.min() < want.max()
 
 
 def test_fuse_swu_collapses_pairs():

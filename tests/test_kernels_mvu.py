@@ -4,11 +4,13 @@ Sweeps shapes (including non-multiples of every tile dim), all three SIMD
 datapaths, both epilogues, and odd block shapes — interpret mode on CPU.
 """
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
-import jax.numpy as jnp
 
 from repro.kernels import ops, packing, ref
+from repro.kernels._common import SUBLANE, epilogue_value
 
 SHAPES = [
     (1, 1, 32),      # degenerate
@@ -62,32 +64,108 @@ def test_xnor_matches_oracle(m, n, k, bkw):
     np.testing.assert_array_equal(want, manual)
 
 
-@pytest.mark.parametrize("mode", ["standard", "binary", "xnor"])
-@pytest.mark.parametrize("n_thresh", [1, 3, 15])
-def test_threshold_epilogue(mode, n_thresh):
-    m, n, k = 17, 29, 96
+def _threshold_table(acc, n_thresh, table, seed):
+    """A (N, T) ascending threshold table against an (M, N) accumulator.
+
+    ``spread``: random levels over the accumulator's range; ``ties``:
+    levels drawn from each column's own accumulator values, so compares hit
+    equality; ``below``/``above``: wholly under or over that range."""
+    rng = np.random.default_rng(seed)
+    n = acc.shape[1]
+    if table == "spread":
+        t = rng.integers(acc.min(), acc.max() + 1, (n, n_thresh))
+    elif table == "ties":
+        rows = rng.integers(0, acc.shape[0], (n, n_thresh))
+        t = acc.T[np.arange(n)[:, None], rows]
+    elif table == "below":
+        t = acc.min() - 1 - rng.integers(0, 50, (n, n_thresh))
+    else:
+        t = acc.max() + 1 + rng.integers(0, 50, (n, n_thresh))
+    return np.sort(t, axis=1).astype(np.int32)
+
+
+def _threshold_params(cases):
+    """pytest params ``(mode, n_thresh, table, n)``, ids ``T-mode[-extra]``."""
+    params = []
+    for mode, n_thresh, table, n in cases:
+        extra = "" if table == "spread" else f"-{table}"
+        extra += "" if n == 29 else f"-n{n}"
+        params.append(pytest.param(mode, n_thresh, table, n,
+                                   id=f"{n_thresh}-{mode}{extra}"))
+    return params
+
+
+THRESHOLD_CASES = _threshold_params(
+    [(mode, t, "spread", 29) for t in (1, 3, 15, 255)
+     for mode in ("standard", "binary", "xnor", "wide")]
+    + [(mode, t, table, 29) for table in ("ties", "below", "above")
+       for mode, t in (("standard", 3), ("xnor", 1), ("binary", 255))]
+    + [("standard", 3, "ties", 150), ("xnor", 1, "spread", 150),
+       ("wide", 255, "ties", 150)])
+
+
+@pytest.mark.parametrize("mode,n_thresh,table,n", THRESHOLD_CASES)
+def test_threshold_epilogue(mode, n_thresh, table, n):
+    """Every SIMD datapath's fused multi-threshold epilogue == ref.py.
+
+    T = 15 and 255 run the looped form, ``wide`` (uint8 activations) the XLA
+    epilogue after one kernel pass per base-256 digit, n = 150 two N
+    blocks of 128, each with its own slice of the table."""
+    m, k = 17, 96
     if mode == "xnor":
         ab = _rand((m, k), 0, 2, 7, np.int32)
         wb = _rand((n, k), 0, 2, 8, np.int32)
         a = packing.pack_bits(jnp.asarray(ab))
         w = packing.pack_bits(jnp.asarray(wb))
-        acc = (2 * ab - 1) @ (2 * wb - 1).T
+        oracle = lambda t=None: ref.mvu_xnor_ref(a, w, k, t)
     elif mode == "binary":
-        a_ = _rand((m, k), -8, 8, 9)
-        wb = _rand((n, k), 0, 2, 10)
-        a, w = jnp.asarray(a_), jnp.asarray(wb)
-        acc = a_.astype(np.int64) @ (2 * wb.astype(np.int64) - 1).T
+        a = jnp.asarray(_rand((m, k), -8, 8, 9))
+        w = jnp.asarray(_rand((n, k), 0, 2, 10))
+        oracle = lambda t=None: ref.mvu_binary_ref(a, w, t)
     else:
-        a_ = _rand((m, k), -8, 8, 11)
-        w_ = _rand((n, k), -8, 8, 12)
-        a, w = jnp.asarray(a_), jnp.asarray(w_)
-        acc = a_.astype(np.int64) @ w_.astype(np.int64).T
-    t = np.sort(_rand((n, n_thresh), -300, 300, 13, np.int32), axis=1)
-    want = (acc[..., None] >= t[None]).sum(-1)
-    got = ops.mvu(a, w, mode, k_bits=k, thresholds=jnp.asarray(t),
-                  block_m=32, block_n=32, block_k=32, block_kw=2)
+        a = jnp.asarray(_rand((m, k), -8, 8, 11) if mode == "standard"
+                        else _rand((m, k), 0, 256, 11, np.uint8))
+        w = jnp.asarray(_rand((n, k), -8, 8, 12))
+        oracle = lambda t=None: ref.mvu_int_ref(a, w, t)
+    acc = np.asarray(oracle())
+    t = _threshold_table(acc, n_thresh, table, 13)
+    want = np.asarray(oracle(jnp.asarray(t)))
+    np.testing.assert_array_equal(want, (acc[..., None] >= t[None]).sum(-1))
+    got = ops.mvu(a, w, "standard" if mode == "wide" else mode, k_bits=k,
+                  thresholds=jnp.asarray(t), block_m=32,
+                  block_n=128 if n > 128 else 32, block_k=32, block_kw=2)
     np.testing.assert_array_equal(np.asarray(got), want)
-    assert np.asarray(got).max() <= n_thresh and np.asarray(got).min() >= 0
+    levels = {"below": {n_thresh}, "above": {0}}.get(table)
+    if levels is not None:
+        assert set(np.unique(want)) == levels
+    else:
+        assert np.asarray(got).max() <= n_thresh and want.min() < want.max()
+
+
+def _value_shapes(jaxpr):
+    """Shape of every value a jaxpr makes, inside nested jaxprs too."""
+    for eqn in jaxpr.eqns:
+        yield from (tuple(v.aval.shape) for v in eqn.outvars)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _value_shapes(sub)
+
+
+@pytest.mark.parametrize("n_thresh", [1, 3, 15, 255])
+def test_threshold_epilogue_keeps_channels_on_lanes(n_thresh):
+    """The multi-threshold epilogue of an (M, bn) tile with a (T, bn) table
+    keeps the channels on the last (lane) axis of every value it makes: no
+    (M, bn, T) broadcast that would put the T levels on lanes.  A 3-D value
+    only ever stacks at most one sublane group of threshold rows ahead of
+    the tile, in the one-step and the looped form alike."""
+    m, bn = 16, 128
+    jaxpr = jax.make_jaxpr(lambda acc, t: epilogue_value(acc, t, None))(
+        jnp.zeros((m, bn), jnp.int32), jnp.zeros((n_thresh, bn), jnp.int32))
+    shapes = set(_value_shapes(jaxpr.jaxpr))
+    assert (m, bn) in shapes
+    for s in shapes:
+        assert len(s) < 2 or s[-1] == bn, sorted(shapes)
+        assert len(s) <= 2 or (len(s) == 3 and s[0] <= SUBLANE
+                               and s[1] in (1, m)), sorted(shapes)
 
 
 @pytest.mark.parametrize("mode", ["standard", "binary"])

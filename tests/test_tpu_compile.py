@@ -168,3 +168,20 @@ def test_wide_activations_compile(one_chip, kind, dtype, passes):
                   ((n, 255), jnp.int32)]
         name = "conv_mvu_standard"
     assert _kernels(fn, one_chip, *shapes) == [name] * passes
+
+
+@pytest.mark.parametrize("kind", ["dense", "conv"])
+def test_looped_threshold_epilogue_compiles(one_chip, kind):
+    """A 255-row threshold table (8-bit activations) inside the kernel: the
+    epilogue loops over aligned 8-row groups of the (T, bn) table."""
+    n, k, thr = 128, 512, ((128, 255), jnp.int32)
+    if kind == "dense":
+        fn = lambda a, w, t: mvu_int_pallas(a, w, t, interpret=False)
+        shapes = [((256, k), jnp.int8), ((n, k), jnp.int8), thr]
+        name = "mvu_int"
+    else:
+        fn = lambda x, w, t: conv_mvu_pallas(x, w, t, kernel=3, mode="standard",
+                                             block_n=n, interpret=False)
+        shapes = [((2, 16, 16, 64), jnp.int8), ((n, 9 * 64), jnp.int8), thr]
+        name = "conv_mvu_standard"
+    assert _kernels(fn, one_chip, *shapes) == [name]
