@@ -1,0 +1,7 @@
+"""Share of the conv_mvu_binary kernel's roofline (device trace)."""
+
+from bench.roofline.share import kernel_share
+
+
+def read(ctx):
+    return kernel_share(ctx, "conv_mvu_binary")

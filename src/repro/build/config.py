@@ -62,7 +62,9 @@ class BuildConfig:
         ``pipeline`` (engine + multi-device ``as_pipeline``), ``serving``
         (engine + measured cycle-time calibration for the batcher).
     mode / weight_bits / act_bits / backend: lowering parameters
-        (``lowering.lower_to_mvu``).
+        (``lowering.lower_to_mvu``); mode and weight_bits are defaults
+        that a conv or linear node's own attrs override, so each MVU gets
+        its own ``MVUConfig``.
     folding: ``"balance"`` rate-balances every stage
         (``lowering.apply_folding`` with ``target_cycles``/``max_pe``/
         ``max_simd``), ``"none"`` keeps heuristic per-layer defaults, or an

@@ -151,10 +151,18 @@ def step_validate(state: BuildState) -> None:
 
 @register_step("lower")
 def step_lower(state: BuildState) -> None:
+    """Lower to MVUs; with telemetry on, count the nodes lowered at each
+    (mode, weight bits, act bits) as ``lower.<mode>.w<b>a<b>`` counters."""
     cfg = state.cfg
     state.graph = lowering.lower_to_mvu(
         state.graph, mode=cfg.mode, weight_bits=cfg.weight_bits,
         act_bits=cfg.act_bits, backend=cfg.backend)
+    if state.tracer is not None:
+        per_config = Counter(
+            f"lower.{c.mode}.w{c.weight_bits}a{c.act_bits}"
+            for c in (n.attrs["config"] for n in state.graph if n.op == "mvu"))
+        for key, count in sorted(per_config.items()):
+            state.tracer.counter(key, count, cat="build")
     state.mark_dirty()
 
 
@@ -464,7 +472,8 @@ def run_pipeline(graph: Graph, cfg: BuildConfig) -> BuildState:
     if cfg.telemetry:
         from repro.telemetry import Tracer
 
-        tracer = Tracer(meta={"build": cfg.name, "target": cfg.target})
+        tracer = state.tracer = Tracer(meta={"build": cfg.name,
+                                             "target": cfg.target})
     steps = cfg.steps if cfg.steps is not None else DEFAULT_STEPS[cfg.target]
     t_build = time.perf_counter()
     for step in steps:
@@ -490,7 +499,6 @@ def run_pipeline(graph: Graph, cfg: BuildConfig) -> BuildState:
     report.total_wall_s = time.perf_counter() - t_build
     if tracer is not None:
         report.telemetry = tracer.summary()
-        state.tracer = tracer
     if state.ref_graph is None and _executable(state.graph):
         state.ref_graph = state.graph
     return state

@@ -173,23 +173,34 @@ def schedule(graph: Graph) -> DataflowSchedule:
             max(2, -(-abs(lat[node.inputs[0]] - lat[node.inputs[1]])
                      // max(1, interval))),
         )
-        for node, _, _ in info if node.op in ir.ELTWISE_OPS
+        for node, _, _ in info if node.op in ir.JOIN_OPS
     ]
     return DataflowSchedule(stages, joins=joins,
                             critical_path_cycles=max(lat.values(), default=0))
 
 
-def _int_range(node, ins: list) -> tuple[int, int] | None:
+def _int_range(node, ins: list, in_shapes: tuple) -> tuple[int, int] | None:
     """Static integer range of one node's output, given its inputs' ranges
-    (None = not a bounded integer stream)."""
+    and shapes (None = not a bounded integer stream)."""
     if node.op == "input":
         bits = node.attrs.get("bits")
+        if bits is None:
+            return None
+        if node.attrs.get("signed") is False:
+            return (0, 2**bits - 1)
         # either sign: the feed may be signed or unsigned on ``bits`` bits
-        return None if bits is None else (1 - 2**bits, 2**bits - 1)
+        return (1 - 2**bits, 2**bits - 1)
     if node.op == "quant_act":
         return (0, 2 ** node.attrs["bits"] - 1)
     if node.op in ("swu", "maxpool", "flatten"):
         return ins[0]
+    if node.op == "global_pool":
+        if ins[0] is None:
+            return None
+        h, w, _ = in_shapes[0]
+        return (ins[0][0] * h * w, ins[0][1] * h * w)
+    if node.op == "add_threshold":
+        return (0, int(node.params["thresholds"].shape[-1]))
     if node.op in ("mvu", "conv_mvu"):
         p = node.params.get("mvu")
         if p is None or p.thresholds is None:
@@ -210,37 +221,61 @@ def _int_range(node, ins: list) -> tuple[int, int] | None:
     return None
 
 
-def int8_inputs(graph) -> frozenset[str]:
-    """The mvu/conv_mvu nodes whose input stream provably fits int8.
-
-    Static ranges: an input node's ``bits`` (either sign), a quant_act's
-    grid, a thresholded MVU's levels [0, T], carried through
-    swu/maxpool/flatten and combined by the eltwise ops with integer
-    scales; anything else is unknown.  :func:`node_runner` narrows these
-    streams to int8, so the MXU kernels run one pass; any other stream is
-    computed exactly in base-256 digits (``kernels._common.by_int8_digits``).
-    """
+def int_ranges(graph) -> dict[str, tuple[int, int] | None]:
+    """Every node's static output range, keyed by name (:func:`_int_range`
+    carried along the edges in dataflow order)."""
     ranges: dict[str, tuple[int, int] | None] = {}
-    out = set()
-    for node in ir.toposort(graph):
-        ins = [ranges.get(s) for s in node.inputs]
-        if (node.op in ("mvu", "conv_mvu") and ins[0] is not None
-                and -128 <= ins[0][0] and ins[0][1] <= 127):
-            out.add(node.name)
-        ranges[node.name] = _int_range(node, ins)
-    return frozenset(out)
+    for node, in_shapes, _ in ir.io_shapes(graph):
+        ranges[node.name] = _int_range(
+            node, [ranges.get(s) for s in node.inputs], in_shapes)
+    return ranges
 
 
-def node_runner(node, *, int8_input: bool = False):
+# the integer dtypes an MVU's input stream may be narrowed to, narrowest
+# first: int8 feeds the MXU in one pass, uint8 in two base-256 digits,
+# int16 in three (``kernels._common.int8_digits``)
+NARROW_DTYPES = (jnp.int8, jnp.uint8, jnp.int16)
+
+
+def narrow_inputs(graph) -> dict[str, jnp.dtype]:
+    """The mvu/conv_mvu nodes whose input stream provably fits a narrower
+    integer dtype, each with the narrowest that holds it.
+
+    Static ranges, each proved from the node's own producer: an input
+    node's ``bits`` (unsigned where ``signed`` is False, else either sign),
+    a quant_act's grid, a thresholded MVU's or join's levels [0, T], a
+    global pool's sum over its map, carried through swu/maxpool/flatten and
+    combined by the eltwise ops with integer scales; anything else is
+    unknown.  :func:`node_runner` casts these streams, so an int8 stream
+    runs the MXU kernels in one pass and a wider one in exactly as many
+    base-256 digits as its range needs (``kernels._common.by_int8_digits``).
+    """
+    ranges = int_ranges(graph)
+    out = {}
+    for node in ir.as_graph(graph):
+        if node.op not in ("mvu", "conv_mvu"):
+            continue
+        r = ranges.get(node.inputs[0])
+        if r is None:
+            continue
+        dtype = next((d for d in NARROW_DTYPES
+                      if jnp.iinfo(d).min <= r[0] and r[1] <= jnp.iinfo(d).max),
+                     None)
+        if dtype is not None:
+            out[node.name] = jnp.dtype(dtype)
+    return out
+
+
+def node_runner(node, *, in_dtype=None):
     """Per-node semantics as ``(params, fn)`` with ``fn(params, *xs) -> x``.
 
     The eager interpreter (:func:`execute`) and the fused engine
     (``repro.core.engine``) both apply nodes through this single definition,
     so the jit-compiled engine is bit-exact with the behavioural model by
     construction.  ``params`` is the node's traced pytree (or ``None``).
-    Single-input ops take one array; elementwise-binary ops take two.
-    ``int8_input`` (from :func:`int8_inputs`) carries an MVU's input in
-    int8, which the range proof makes lossless.
+    Single-input ops take one array; join ops take two.  ``in_dtype``
+    (from :func:`narrow_inputs`) carries an MVU's input in that dtype,
+    which the range proof makes lossless.
     """
     if node.op == "input":
         return None, lambda p, x: x
@@ -259,6 +294,10 @@ def node_runner(node, *, int8_input: bool = False):
             return opf(a2 * sa, b2 * sb)
 
         return None, run_eltwise
+    if node.op == "add_threshold":
+        scales = tuple(node.attrs.get("scales", (1, 1)))
+        return node.params["thresholds"], lambda t, a, b: ops.add_threshold(
+            a, b, t, scales=scales)
     if node.op == "swu":
         kd, st, pd = node.attrs["kernel"], node.attrs["stride"], node.attrs["pad"]
 
@@ -277,8 +316,8 @@ def node_runner(node, *, int8_input: bool = False):
 
         def run_conv(p, x):
             b, h, w, _ = x.shape
-            if int8_input:
-                x = x.astype(jnp.int8)
+            if in_dtype is not None:
+                x = x.astype(in_dtype)
             out = ops.conv_mvu(
                 x, p.weights,
                 kernel=kd, stride=st, pad=pd, mode=cfg.mode,
@@ -294,16 +333,21 @@ def node_runner(node, *, int8_input: bool = False):
     if node.op == "maxpool":
         size = node.attrs["size"]
         st = node.attrs.get("stride", size)
+        pd = node.attrs.get("pad", 0)
 
         def run_pool(p, x):
+            # padded cells hold the dtype's least value: they never win
             init = x.dtype.type(jnp.iinfo(x.dtype).min) if jnp.issubdtype(
                 x.dtype, jnp.integer) else x.dtype.type(-jnp.inf)
             return jax.lax.reduce_window(
                 x, init, jax.lax.max,
-                (1, size, size, 1), (1, st, st, 1), "VALID",
+                (1, size, size, 1), (1, st, st, 1),
+                ((0, 0), (pd, pd), (pd, pd), (0, 0)),
             )
 
         return None, run_pool
+    if node.op == "global_pool":
+        return None, lambda p, x: jnp.sum(x, axis=(1, 2), dtype=jnp.int32)
     if node.op == "flatten":
         return None, lambda p, x: x.reshape(x.shape[0], -1)
     if node.op == "mvu":
@@ -313,8 +357,8 @@ def node_runner(node, *, int8_input: bool = False):
         def run_mvu(p, x):
             if cfg.mode == "xnor" and x.dtype != jnp.uint32:
                 x = packing.pack_bits(x.astype(jnp.int32))
-            elif int8_input:
-                x = x.astype(jnp.int8)
+            elif in_dtype is not None:
+                x = x.astype(in_dtype)
             return layer(p, x)
 
         return node.params["mvu"], run_mvu
@@ -355,9 +399,9 @@ def trace(graph: Graph, x) -> dict[str, jax.Array]:
                 "{name: array} dict instead of one array")
         feeds = {heads[0].name: x}
     env: dict[str, jax.Array] = {}
-    narrow = int8_inputs(order)
+    narrow = narrow_inputs(order)
     for node in order:
-        params, fn = node_runner(node, int8_input=node.name in narrow)
+        params, fn = node_runner(node, in_dtype=narrow.get(node.name))
         if node.op == "input":
             if node.name not in feeds:
                 raise ValueError(f"no feed for input node {node.name!r}")

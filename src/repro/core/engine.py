@@ -110,8 +110,8 @@ class FusedEngine:
         # stage order is the dataflow (topological) order -- identical to
         # list order for chains, and the streaming order for branched graphs
         order = ir.toposort(self.graph)
-        narrow = dataflow.int8_inputs(order)
-        runners = [dataflow.node_runner(n, int8_input=n.name in narrow)
+        narrow = dataflow.narrow_inputs(order)
+        runners = [dataflow.node_runner(n, in_dtype=narrow.get(n.name))
                    for n in order]
         self._fns = tuple(fn for _, fn in runners)
         self.params = [p for p, _ in runners]
@@ -263,7 +263,8 @@ class FusedEngine:
         if scl[0] is not None:
             stacked["s"] = jnp.stack(scl)
         # one stage fn runs every layer: narrow only if every input fits
-        narrow = {n.name for n in non_input} <= dataflow.int8_inputs(self.graph)
+        dtypes = dataflow.narrow_inputs(self.graph)
+        narrow = all(dtypes.get(n.name) == jnp.int8 for n in non_input)
         layer_fn = kops.mvu_layer_fn(
             cfgs[0].mode, backend=cfgs[0].backend, int8_input=narrow,
             **cfgs[0].kernel_blocks()

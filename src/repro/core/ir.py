@@ -14,12 +14,21 @@ Transformation passes (lowering.py) rewrite the graph exactly like FINN's
 dataflow.py then plays the role of *Folding and Resource Estimation*.
 
 Supported ops:
-    input            attrs: shape, bits                 (0 inputs)
+    input            attrs: shape, bits, signed (optional: False for an
+                     unsigned feed; unset, the feed may take either sign)
+                     (0 inputs)
     conv             attrs: kernel, stride, pad; params: w (Kd,Kd,Cin,Cout)
     linear           attrs: -; params: w (N, K) float
+                     (conv and linear may set mode / weight_bits, which
+                     override the build's defaults for that node:
+                     ``lowering.lower_to_mvu``)
     batchnorm        params: gamma, beta, mean, var
     quant_act        attrs: bits, act_scale
-    maxpool          attrs: size, stride (defaults to size)
+    maxpool          attrs: size, stride (defaults to size), pad (default
+                     0; padded cells never win the max)
+    global_pool      attrs: -; (H, W, C) -> (C,), the sum over the map
+                     (FINN's GlobalAccPool: an average's 1/(H*W) is left to
+                     whatever consumes it)
     flatten          attrs: -
     swu              attrs: kernel, stride, pad  (after lowering)
     mvu              attrs: MVUConfig; params: MVUParams (after lowering)
@@ -28,6 +37,10 @@ Supported ops:
     add / sub / mul  attrs: scales=(sa, sb) optional per-input integer
                      quantization-alignment scales (default (1, 1));
                      2 inputs, FINN elementwise-binary broadcast semantics
+    add_threshold    attrs: scales; params: thresholds (N, T) int32
+                     (after ``lowering.fuse_epilogues`` folds an
+                     ``add -> quant_act`` join: FINN's AddStreams followed
+                     by Thresholding, one node); 2 inputs of one shape
 """
 
 from __future__ import annotations
@@ -57,15 +70,18 @@ class Graph(list):
 # the streaming elementwise-binary family (FINN ElementwiseBinaryOperation)
 ELTWISE_OPS = ("add", "sub", "mul")
 
+# every op that joins two streams
+JOIN_OPS = (*ELTWISE_OPS, "add_threshold")
+
 KNOWN_OPS = {
-    "input", "conv", "linear", "batchnorm", "quant_act",
-    "maxpool", "flatten", "swu", "mvu", "conv_mvu", *ELTWISE_OPS,
+    "input", "conv", "linear", "batchnorm", "quant_act", "maxpool",
+    "global_pool", "flatten", "swu", "mvu", "conv_mvu", *JOIN_OPS,
 }
 
 
 # ops that consume a spatial (H, W, C) activation; everything else takes
 # whatever its producer yields
-SPATIAL_OPS = ("conv", "swu", "conv_mvu", "maxpool")
+SPATIAL_OPS = ("conv", "swu", "conv_mvu", "maxpool", "global_pool")
 
 # one DeprecationWarning per process for each legacy entry point (the
 # EngineServer shim pattern)
@@ -211,7 +227,7 @@ def validate_graph(graph) -> None:
                 raise ValueError(
                     f"{describe(n)}: dangling input edge from {src!r} -- no "
                     f"node of that name in the graph")
-        want = 0 if n.op == "input" else 2 if n.op in ELTWISE_OPS else 1
+        want = 0 if n.op == "input" else 2 if n.op in JOIN_OPS else 1
         if len(n.inputs) != want:
             if n.op == "input":
                 raise ValueError(
@@ -328,7 +344,7 @@ def propagate(node: Node, *input_shapes: tuple) -> tuple:
                          *(() if shape is None else (tuple(shape),)))
     if node.op == "input":
         return tuple(node.attrs["shape"])
-    if node.op in ELTWISE_OPS:
+    if node.op in JOIN_OPS:
         if len(input_shapes) != 2:
             raise ValueError(
                 f"{node.op!r} takes exactly 2 input shapes, got "
@@ -341,7 +357,7 @@ def propagate(node: Node, *input_shapes: tuple) -> tuple:
         h, w = shape[0], shape[1]
         if node.op == "maxpool":
             kd = node.attrs["size"]
-            st, pd = node.attrs.get("stride", kd), 0
+            st, pd = node.attrs.get("stride", kd), node.attrs.get("pad", 0)
         else:
             kd = node.attrs["kernel"]
             st, pd = node.attrs["stride"], node.attrs["pad"]
@@ -353,6 +369,8 @@ def propagate(node: Node, *input_shapes: tuple) -> tuple:
         n = (node.params["w"].shape[-1] if node.op == "conv"
              else node.attrs["config"].out_features)
         return (oh, ow, n)
+    if node.op == "global_pool":
+        return (shape[2],)
     if node.op == "flatten":
         size = 1
         for d in shape:

@@ -1,8 +1,9 @@
 """Graph transformation passes: FINN's lowering + streamlining, in JAX.
 
-    lower_to_mvu:   conv -> [swu, mvu];  linear -> mvu
+    lower_to_mvu:   conv -> [swu, mvu];  linear -> mvu  (per-node MVUConfig)
     streamline:     [mvu, batchnorm, quant_act] -> mvu(+thresholds)
-    fuse_epilogues: same fold for finalized graphs (the runtime engine path)
+    fuse_epilogues: same fold for finalized graphs (the runtime engine path),
+                    and [add, quant_act] -> add_threshold
     fuse_swu:       [swu, mvu] -> conv_mvu (line-buffer fused conv kernel)
     apply_folding:  attach rate-balanced Folding to every mvu/conv_mvu node
     apply_schedules: pin empirically tuned kernel schedules from the
@@ -22,7 +23,7 @@ import dataclasses
 
 import jax.numpy as jnp
 
-from repro.core import ir, swu as swu_mod
+from repro.core import dataflow, ir, swu as swu_mod
 from repro.core.folding import balance_pipeline
 from repro.core.ir import Graph, Node, validate_graph
 from repro.core.mvu import MVUConfig, MVULayer
@@ -52,33 +53,38 @@ def _sole_consumer(cons: dict[str, list[Node]], name: str, op: str) -> Node | No
 def lower_to_mvu(graph: Graph, *, mode: str = "standard",
                  weight_bits: int = 4, act_bits: int = 4,
                  backend: str = "pallas") -> Graph:
-    """conv -> swu+mvu; linear -> mvu. Float weights stay attached (raw)."""
+    """conv -> swu+mvu; linear -> mvu. Float weights stay attached (raw).
+
+    Every MVU gets its own :class:`MVUConfig`: a conv or linear node's
+    ``mode`` / ``weight_bits`` attrs win over the defaults given here, so
+    one graph may mix datapaths and bit widths (an 8-bit stem and head
+    around a binary body); each output's bits come from its quant_act
+    (:func:`streamline`, :func:`fuse_epilogues`)."""
     validate_graph(graph)
     out = Graph()
     renames: dict[str, str] = {}
+
+    def config(node: Node, n: int, k: int) -> MVUConfig:
+        a = node.attrs
+        return MVUConfig(
+            in_features=k, out_features=n, mode=a.get("mode", mode),
+            weight_bits=a.get("weight_bits", weight_bits), act_bits=act_bits,
+            backend=backend,
+        )
+
     for node in ir.as_graph(graph):
         if node.op == "conv":
-            kd = node.attrs["kernel"]
             out.append(Node("swu", node.name + ".swu", dict(node.attrs),
                             inputs=node.inputs))
             wm = swu_mod.pack_conv_weights(node.params["w"])  # (N, K)
-            cfg = MVUConfig(
-                in_features=wm.shape[1], out_features=wm.shape[0],
-                mode=mode, weight_bits=weight_bits, act_bits=act_bits,
-                backend=backend,
-            )
             out.append(Node("mvu", node.name + ".mvu",
-                            {"config": cfg}, {"w_float": wm},
-                            inputs=(node.name + ".swu",)))
+                            {"config": config(node, *wm.shape)},
+                            {"w_float": wm}, inputs=(node.name + ".swu",)))
             renames[node.name] = node.name + ".mvu"
         elif node.op == "linear":
             w = node.params["w"]
-            cfg = MVUConfig(
-                in_features=w.shape[1], out_features=w.shape[0],
-                mode=mode, weight_bits=weight_bits, act_bits=act_bits,
-                backend=backend,
-            )
-            out.append(Node("mvu", node.name + ".mvu", {"config": cfg},
+            out.append(Node("mvu", node.name + ".mvu",
+                            {"config": config(node, *w.shape)},
                             {"w_float": w}, inputs=node.inputs))
             renames[node.name] = node.name + ".mvu"
         else:
@@ -199,6 +205,7 @@ def fuse_epilogues(graph: Graph) -> Graph:
     fused node:
         mvu -> batchnorm -> quant_act   =>  mvu(+thresholds)
         mvu -> quant_act                =>  mvu(+thresholds)  (identity BN)
+        add -> quant_act                =>  add_threshold     (residual join)
     """
     from repro.core.mvu import MVUParams
 
@@ -207,7 +214,13 @@ def fuse_epilogues(graph: Graph) -> Graph:
     drop: set[str] = set()
     fused_nodes: dict[str, Node] = {}
     renames: dict[str, str] = {}
+    joins = _fuse_joins(g, cons)
     for node in g:
+        if node.name in joins:
+            fused_nodes[node.name], qa = joins[node.name]
+            drop.add(qa.name)
+            renames[qa.name] = node.name
+            continue
         fusable = (
             node.op in ("mvu", "conv_mvu")
             and "mvu" in node.params
@@ -261,6 +274,38 @@ def fuse_epilogues(graph: Graph) -> Graph:
         renames[qa.name] = node.name
     out = Graph(fused_nodes.get(n.name, n) for n in g if n.name not in drop)
     return _reroute(out, renames)
+
+
+def _fuse_joins(g: Graph, cons) -> dict[str, tuple[Node, Node]]:
+    """``add -> quant_act`` joins of two integer streams of one shape, as
+    ``{add name: (add_threshold node, the quant_act it absorbs)}``.
+
+    For an integer sum x the quantizer's level j (``x >= (j - 0.5) * s``,
+    round half up) is ``x >= ceil((j - 0.5) * s)``: integer thresholds,
+    the same for every channel."""
+    ranges = shapes = None
+    out = {}
+    for node in g:
+        qa = (_sole_consumer(cons, node.name, "quant_act")
+              if node.op == "add" else None)
+        if qa is None:
+            continue
+        if ranges is None:
+            ranges, shapes = dataflow.int_ranges(g), ir.infer_shapes(g)
+        a, b = node.inputs
+        scales = tuple(node.attrs.get("scales", (1, 1)))
+        if (shapes[a] != shapes[b] or ranges[node.name] is None
+                or not all(isinstance(x, int) for x in scales)):
+            continue
+        bits = qa.attrs["bits"]
+        levels = jnp.arange(1, 2**bits, dtype=jnp.float32)
+        t = jnp.ceil((levels - 0.5) * qa.attrs.get("act_scale", 1.0))
+        n = shapes[node.name][-1]
+        thr = jnp.broadcast_to(t.astype(jnp.int32), (n, 2**bits - 1))
+        out[node.name] = (Node("add_threshold", node.name,
+                               {"scales": scales, "fused": (qa.name,)},
+                               {"thresholds": thr}, inputs=node.inputs), qa)
+    return out
 
 
 def fuse_swu(graph: Graph) -> Graph:

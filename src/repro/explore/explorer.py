@@ -41,7 +41,7 @@ import numpy as np
 
 from repro.build import build
 from repro.core import autotune, resource_model
-from repro.core.dataflow import int8_inputs, node_runner
+from repro.core.dataflow import narrow_inputs, node_runner
 from repro.core.ir import Graph
 from repro.explore.grid import SweepPoint, layer_shapes, sweep_grid
 from repro.explore.pareto import pareto_front
@@ -148,9 +148,9 @@ def _measure_point(acc, x, *, reps: int) -> dict:
 
     node_times: dict[str, float] = {}
     cur = x
-    narrow = int8_inputs(acc.graph)
+    narrow = narrow_inputs(acc.graph)
     for node in acc.graph:
-        params, fn = node_runner(node, int8_input=node.name in narrow)
+        params, fn = node_runner(node, in_dtype=narrow.get(node.name))
         if node.op in ("mvu", "conv_mvu"):
             timed = jax.jit(fn)
             node_times[node.name] = _time_median(

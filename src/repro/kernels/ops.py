@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from repro.kernels import mvu_packed as packed_kernels
 from repro.kernels import packing, ref
 from repro.kernels._common import default_interpret
+from repro.kernels.add_threshold import add_threshold_pallas
 from repro.kernels.mvu_binary import mvu_binary_pallas
 from repro.kernels.mvu_int import mvu_int_pallas
 from repro.kernels.mvu_xnor import mvu_xnor_pallas
@@ -71,7 +72,7 @@ def mvu_layer_fn(mode: str = "standard", *, backend: str = "pallas",
     ``repro.core.engine.FusedEngine.as_pipeline`` to run one MVU per
     pipeline stage through ``repro.distributed.pipeline.pipeline_apply``.
     ``int8_input`` narrows ``x`` to int8 first: only where the caller has
-    proved every value fits (``dataflow.int8_inputs``).
+    proved every value fits (``dataflow.narrow_inputs``).
     """
 
     def fn(params, x):
@@ -88,6 +89,31 @@ def mvu_layer_fn(mode: str = "standard", *, backend: str = "pallas",
         )
 
     return fn
+
+
+def add_threshold(
+    a: jax.Array,
+    b: jax.Array,
+    thresholds: jax.Array,
+    *,
+    scales: tuple[int, int] = (1, 1),
+    interpret: bool | None = None,
+) -> jax.Array:
+    """Residual join re-quantized: thresholds(sa * a + sb * b) over the
+    last axis' channels -> int32 levels of ``a``'s shape.
+
+    a, b: integer streams of one shape (..., N); thresholds: (N, T) int32.
+    ``ref.add_threshold_ref`` is its oracle.
+    """
+    if a.shape != b.shape:
+        raise ValueError(f"add_threshold joins streams of one shape, got "
+                         f"{a.shape} and {b.shape}")
+    if interpret is None:
+        interpret = default_interpret()
+    n = a.shape[-1]
+    out = add_threshold_pallas(a.reshape(-1, n), b.reshape(-1, n), thresholds,
+                               scales=tuple(scales), interpret=interpret)
+    return out.reshape(a.shape)
 
 
 def conv_mvu(

@@ -118,3 +118,15 @@ def mvu_int_ref(
         preferred_element_type=jnp.int32,
     )
     return _epilogue(acc, thresholds, out_scale)
+
+
+def add_threshold_ref(
+    a: jax.Array,
+    b: jax.Array,
+    thresholds: jax.Array,
+    scales: tuple[int, int] = (1, 1),
+) -> jax.Array:
+    """Residual-join oracle: thresholds(sa * a + sb * b), (..., N) int32."""
+    sa, sb = scales
+    acc = a.astype(jnp.int32) * sa + b.astype(jnp.int32) * sb
+    return apply_thresholds(acc, thresholds)

@@ -103,10 +103,10 @@ def conv_vmem_bytes(
     """VMEM working set of one ``conv_mvu_pallas`` grid step, in bytes.
 
     Mirrors the kernel's actual residency: the whole padded image (the
-    line-buffer source), one tap's (rt*OW, C) activation rows, one PE block
-    of the weight matrix, the int32 accumulator and output tiles, and the
-    threshold table.  The autotuner prunes candidate schedules against this
-    before timing.
+    line-buffer source, int8, its columns split by phase), one tap's
+    (rt*OW, C) activation rows, one PE block of the weight matrix, the
+    int32 accumulator and output tiles, and the threshold table.  The
+    autotuner prunes candidate schedules against this before timing.
     """
     oh = out_dim(h, kernel, stride, pad)
     ow = out_dim(w, kernel, stride, pad)
@@ -114,8 +114,7 @@ def conv_vmem_bytes(
                              rows_per_tile=rows_per_tile).values()
     top, bottom = _padded_rows(h, oh, rt, kernel=kernel, stride=stride,
                                pad=pad)
-    # line-buffer source: int8, or int32 where the taps are strided loads
-    image = (h + top + bottom) * (w + 2 * pad) * c * _image_bytes(stride)
+    image = (h + top + bottom) * _phase_width(w + 2 * pad, stride) * c
     a_tap = rt * ow * c  # int8 rows of one (ky, kx) tap
     w_tile = bn * k  # int8 PE block, full K
     acc_tile = rt * ow * bn * 4
@@ -124,27 +123,43 @@ def conv_vmem_bytes(
     return int(image + a_tap + w_tile + acc_tile + out_tile + thr)
 
 
-def _image_bytes(stride: int) -> int:
-    """Bytes per resident image element: the chip's strided loads take
-    32-bit data only, so a strided conv keeps its image in int32."""
-    return 1 if stride == 1 else 4
+def _phase_width(wp: int, stride: int) -> int:
+    """Columns of the resident image: ``stride`` phases of ceil(wp/stride)."""
+    return stride * -(-wp // stride)
 
 
-def _kernel(*refs, kernel: int, stride: int, ow: int, rt: int, k: int,
-            mode: str, has_thresh: bool, has_scale: bool):
+def _split_phases(x: jax.Array, stride: int) -> jax.Array:
+    """(B, H, W, C) -> (B, H, stride * ceil(W/stride), C): the columns
+    regrouped by ``col % stride``, phase after phase.  Tap ``kx`` of a
+    strided conv then reads ``ow`` contiguous columns of phase
+    ``kx % stride`` (the chip's strided loads take only 32-bit rows of 128
+    lanes, so the kernel never strides)."""
+    if stride == 1:
+        return x
+    b, h, w, c = x.shape
+    wq = -(-w // stride)
+    x = jnp.pad(x, ((0, 0), (0, 0), (0, stride * wq - w), (0, 0)))
+    x = x.reshape(b, h, wq, stride, c).transpose(0, 1, 3, 2, 4)
+    return x.reshape(b, h, stride * wq, c)
+
+
+def _kernel(*refs, kernel: int, stride: int, ow: int, wq: int, rt: int,
+            k: int, mode: str, has_thresh: bool, has_scale: bool):
     (x_ref, w_ref), t_ref, s_ref, (o_ref,) = split_refs(
         refs, 2, has_thresh, has_scale)
     t = pl.program_id(1)
 
     # Line-buffer taps: for each (ky, kx) only the rt output rows' kernel
-    # rows are touched, each a static slice of the resident image, so no
-    # im2col matrix ever exists outside this kernel.
+    # rows are touched, each a static slice of the resident image (of the
+    # tap's column phase, ``_split_phases``), so no im2col matrix ever
+    # exists outside this kernel.
     acc = rowsum = colsum = None
     for ky in range(kernel):
         for kx in range(kernel):
+            col = (kx % stride) * wq + kx // stride
             a = jnp.concatenate(
-                [x_ref[0, (t * rt + r) * stride + ky, pl.ds(kx, ow, stride), :]
-                 for r in range(rt)], axis=0).astype(jnp.int8)  # (rt*OW, C)
+                [x_ref[0, (t * rt + r) * stride + ky, pl.ds(col, ow), :]
+                 for r in range(rt)], axis=0)  # (rt*OW, C) int8
             w_tap = w_ref[ky * kernel + kx]  # (C, bn) int8
             dot = jax.lax.dot_general(
                 a, w_tap, (((1,), (0,)), ((), ())),
@@ -222,10 +237,11 @@ def conv_mvu_pallas(
                              rows_per_tile=rows_per_tile).values()
     n_tiles = -(-oh // rt)
     x_p = jnp.pad(
-        x.astype(jnp.int8 if _image_bytes(stride) == 1 else jnp.int32),
+        x.astype(jnp.int8),
         ((0, 0), _padded_rows(h, oh, rt, kernel=kernel, stride=stride,
                               pad=pad), (pad, pad), (0, 0)),
     )
+    x_p = _split_phases(x_p, stride)
     hp, wp = x_p.shape[1], x_p.shape[2]
     # (N, Kd^2*C) -> (Kd^2, C, N): one (C, PE) weight slice per tap
     w_t = pad_to(w.astype(jnp.int8), 0, bn).reshape(-1, kernel * kernel, c)
@@ -237,8 +253,9 @@ def conv_mvu_pallas(
 
     out = pl.pallas_call(
         functools.partial(
-            _kernel, kernel=kernel, stride=stride, ow=ow, rt=rt, k=k,
-            mode=mode, has_thresh=has_thresh, has_scale=has_scale,
+            _kernel, kernel=kernel, stride=stride, ow=ow, wq=wp // stride,
+            rt=rt, k=k, mode=mode, has_thresh=has_thresh,
+            has_scale=has_scale,
         ),
         grid=(b, n_tiles, np_ // bn),
         in_specs=[

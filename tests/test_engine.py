@@ -105,7 +105,9 @@ def test_fused_engine_matches_interpreter_conv(mode):
 def test_engine_exact_at_act_bits(mode, bits):
     """8-bit activations (levels up to 255) do not fit the MXU's int8: the
     engine must stay bit-exact with the ``backend="xla"`` (ref.py) build,
-    and only streams proven to fit int8 are narrowed."""
+    only streams proven to fit int8 are narrowed to it, and wider ones to
+    the narrowest dtype that holds them (uint8 levels, int16 for an 8-bit
+    input of either sign)."""
     rng = np.random.default_rng(17)
     dims = [48, 40, 24, 8]
     g = _mlp_graph(rng, dims, bits)
@@ -119,7 +121,10 @@ def test_engine_exact_at_act_bits(mode, bits):
     np.testing.assert_array_equal(
         np.asarray(dataflow.execute(graphs["pallas"], x)), want)
     mvus = {n.name for n in engine.graph if n.op == "mvu"}
-    assert dataflow.int8_inputs(engine.graph) == (mvus if bits == 2 else set())
+    want_dtypes = ({n: jnp.int8 for n in mvus} if bits == 2 else
+                   {"fc0.mvu": jnp.int16, "fc1.mvu": jnp.uint8,
+                    "fc2.mvu": jnp.uint8})
+    assert dataflow.narrow_inputs(engine.graph) == want_dtypes
     if bits == 8:  # the hidden activations really leave the int8 range
         env = dataflow.trace(engine.graph, x)
         assert max(int(np.asarray(env[n]).max()) for n in mvus

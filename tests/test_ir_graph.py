@@ -244,9 +244,9 @@ def test_schedule_reports_branch_joins():
     (7, "sub", (1, 1), False),    # 127 - (-127) leaves int8
 ])
 def test_int8_inputs_proves_ranges_through_joins(bits, op, scales, narrow):
-    """``dataflow.int8_inputs`` narrows an MVU's input only where the static
-    range (input bits of either sign, quant_act grids, eltwise with integer
-    scales) fits int8; ``head`` reads the join."""
+    """``dataflow.narrow_inputs`` narrows an MVU's input to int8 only where
+    the static range (input bits of either sign, quant_act grids, eltwise
+    with integer scales) fits int8; ``head`` reads the join."""
     from repro.core.mvu import MVUConfig
 
     def mvu(name, src):
@@ -259,6 +259,6 @@ def test_int8_inputs_proves_ranges_through_joins(bits, op, scales, narrow):
         Node(op, "join", {"scales": scales}, inputs=("a0", "in")),
         mvu("head", "join"),
     ])
-    got = dataflow.int8_inputs(g)
-    assert ("fc0" in got) == (bits <= 7)
-    assert ("head" in got) == narrow
+    got = dataflow.narrow_inputs(g)
+    assert (got.get("fc0") == jnp.int8) == (bits <= 7)
+    assert (got.get("head") == jnp.int8) == narrow

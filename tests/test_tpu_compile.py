@@ -7,7 +7,8 @@ reshapes Mosaic cannot lay out, more VMEM than a kernel may use.  Interpret
 mode (every other kernel test) cannot see any of that.  Shapes are the real
 ones: the NID-MLP layers with the tiles its build picks, 512x512 dense
 layers at CNV's width, the first two CNV FULL conv layers (and a strided
-conv), and the whole NID engine step.
+conv), ResNet-50 W1A2's stem, strided convs and residual join, and the
+whole NID engine step.
 
 The topology is described inside a fixture, never on import: only one
 process at a time may load the TPU library, and every test worker imports
@@ -30,6 +31,7 @@ from repro.core.mvu import MVUConfig
 from repro.kernels import mvu_packed, ops, packing
 from repro.kernels.mvu_binary import mvu_binary_pallas
 from repro.kernels.mvu_int import mvu_int_pallas
+from repro.kernels.add_threshold import add_threshold_pallas
 from repro.kernels.mvu_xnor import mvu_xnor_pallas
 from repro.kernels.swu_mvu import conv_mvu_pallas
 
@@ -112,7 +114,7 @@ def test_dense_kernels_compile_at_512(one_chip, mode, name):
 @pytest.mark.parametrize("h,c,n,mode,stride,pad", [
     (32, 3, 64, "xnor", 1, 0),  # CNV FULL conv0
     (30, 64, 64, "xnor", 1, 0),  # CNV FULL conv1
-    (32, 64, 128, "standard", 2, 1),  # strided taps load 32-bit rows
+    (32, 64, 128, "standard", 2, 1),  # strided taps read one column phase
 ])
 def test_conv_layers_compile(one_chip, h, c, n, mode, stride, pad):
     k = 9 * c
@@ -122,6 +124,33 @@ def test_conv_layers_compile(one_chip, h, c, n, mode, stride, pad):
     names = _kernels(fn, one_chip, ((8, h, h, c), jnp.int8),
                      ((n, k), jnp.int8), ((n, 1), jnp.int32))
     assert names == [f"conv_mvu_{mode}"]
+
+
+@pytest.mark.parametrize("h,c,n,kernel,stride,pad,mode,dtype,passes", [
+    (224, 3, 64, 7, 2, 3, "standard", jnp.uint8, 2),  # the 8-bit RGB stem
+    (56, 128, 128, 3, 2, 1, "binary", jnp.int8, 1),  # stage 2's strided 3x3
+    (56, 256, 512, 1, 2, 0, "binary", jnp.int8, 1),  # its 1x1 projection
+])
+def test_resnet_convs_compile(one_chip, h, c, n, kernel, stride, pad, mode,
+                              dtype, passes):
+    """ResNet-50 W1A2's strided and padded convs at published shapes, one
+    image per call as the engine runs them: the stem over C = 3 in two
+    digit passes, and wide strided maps whose taps read one column phase
+    each (the chip strides only 32-bit rows of 128 lanes)."""
+    fn = lambda x, w, t: conv_mvu_pallas(x, w, t, kernel=kernel, stride=stride,
+                                         pad=pad, mode=mode,
+                                         block_n=min(n, 128), interpret=False)
+    names = _kernels(fn, one_chip, ((1, h, h, c), dtype),
+                     ((n, kernel * kernel * c), jnp.int8), ((n, 3), jnp.int32))
+    assert names == [f"conv_mvu_{mode}"] * passes
+
+
+def test_residual_join_compiles(one_chip):
+    """The add-and-threshold join over stage 1's 56x56x256 map."""
+    fn = lambda a, b, t: add_threshold_pallas(a, b, t, interpret=False)
+    names = _kernels(fn, one_chip, ((56 * 56, 256), jnp.int32),
+                     ((56 * 56, 256), jnp.int32), ((256, 3), jnp.int32))
+    assert names == ["add_threshold"]
 
 
 def test_nid_engine_step_compiles(one_chip, monkeypatch):
