@@ -49,6 +49,7 @@ from repro.build.report import BuildReport, NodeReport
 from repro.core import dataflow, ir, lowering
 from repro.core.ir import Graph
 from repro.core.mvu import MVUConfig, MVULayer
+from repro.kernels.swu_mvu import gathers_taps
 
 
 # ------------------------------------------------------------------- state
@@ -214,7 +215,18 @@ def step_fuse_epilogues(state: BuildState) -> None:
 
 @register_step("fuse_swu")
 def step_fuse_swu(state: BuildState) -> None:
+    """Collapse ``swu -> mvu`` pairs into ``conv_mvu`` nodes; with telemetry
+    on, count those whose line buffer hands the MXU a whole window per dot
+    (``swu_mvu.gathers_taps``) as the ``lower.conv.gathered_taps`` counter."""
     state.graph = lowering.fuse_swu(state.graph)
+    if state.tracer is not None:
+        gathered = sum(
+            gathers_taps(n.attrs["kernel"],
+                         n.attrs["config"].in_features // n.attrs["kernel"] ** 2)
+            for n in state.graph if n.op == "conv_mvu")
+        if gathered:
+            state.tracer.counter("lower.conv.gathered_taps", gathered,
+                                 cat="build")
     state.mark_dirty()
 
 

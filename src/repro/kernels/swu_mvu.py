@@ -9,10 +9,14 @@ RTL avoids.
 
 This kernel family restores the line-buffer discipline on TPU: the input
 image stays in its natural (B, H, W, C) layout in HBM, and each grid step
-accumulates one tile of output rows *inside the kernel*, one (ky, kx) tap
-at a time: the tap's (rt*OW, C) activation rows are static slices of the
-``Kd`` resident kernel rows (the line buffer), multiplied against the tap's
-(C, PE) slice of the weight matrix and summed in int32, before the fused
+accumulates one tile of output rows *inside the kernel*.  Each (ky, kx)
+tap's (rt*OW, C) activation rows are static slices of the ``Kd`` resident
+kernel rows (the line buffer).  When the whole window fits two lane tiles
+(``gathers_taps``: the RGB stem, CNV's ``conv0``) the taps are gathered on
+lanes into one (rt*OW, K) operand and the MXU takes the window in one dot,
+as FINN's SWU hands the MVU one K-deep window per pixel; otherwise each tap
+is multiplied against its own (C, PE) slice of the weight matrix, whose C
+already fills the lanes.  Either way the sum is int32, before the fused
 multi-threshold epilogue.  The weights keep the (ky, kx, c) feature order
 of :func:`repro.core.swu.pack_conv_weights`, so the same packed weights
 serve both paths.
@@ -20,8 +24,10 @@ serve both paths.
 Grid = (B, row tiles, NF); every step is independent (full-K dot per step),
 mirroring one pass of the FINN SWU/MVU pair over ``rt`` output rows:
 
-    A tap    (rt*OW, C) per (ky, kx), sliced from the Kd-row line buffer
-    W block  (Kd^2, C, PE=bn) weight stream, one NF row group per step
+    A        (rt*OW, K) gathered window, or (rt*OW, C) per (ky, kx) tap,
+             sliced from the Kd-row line buffer
+    W block  (K, PE=bn) or (Kd^2, C, PE=bn) weight stream, one NF row
+             group per step
     epilogue thresholds / scale / raw int32 accumulator (shared MVTU code)
 
 All three weight codings run through the MXU via the usual identities
@@ -103,8 +109,9 @@ def conv_vmem_bytes(
     """VMEM working set of one ``conv_mvu_pallas`` grid step, in bytes.
 
     Mirrors the kernel's actual residency: the whole padded image (the
-    line-buffer source, int8, its columns split by phase), one tap's
-    (rt*OW, C) activation rows, one PE block of the weight matrix, the
+    line-buffer source, int8, its columns split by phase), the gathered
+    (rt*OW, K) window or one tap's (rt*OW, C) activation rows
+    (``gathers_taps``), one PE block of the weight matrix, the
     int32 accumulator and output tiles, and the threshold table.  The
     autotuner prunes candidate schedules against this before timing.
     """
@@ -115,12 +122,13 @@ def conv_vmem_bytes(
     top, bottom = _padded_rows(h, oh, rt, kernel=kernel, stride=stride,
                                pad=pad)
     image = (h + top + bottom) * _phase_width(w + 2 * pad, stride) * c
-    a_tap = rt * ow * c  # int8 rows of one (ky, kx) tap
+    # int8 rows of the gathered window, or of one (ky, kx) tap
+    a_tile = rt * ow * (k if gathers_taps(kernel, c) else c)
     w_tile = bn * k  # int8 PE block, full K
     acc_tile = rt * ow * bn * 4
     out_tile = rt * ow * bn * 4
     thr = bn * n_thresh * 4
-    return int(image + a_tap + w_tile + acc_tile + out_tile + thr)
+    return int(image + a_tile + w_tile + acc_tile + out_tile + thr)
 
 
 def _phase_width(wp: int, stride: int) -> int:
@@ -143,8 +151,20 @@ def _split_phases(x: jax.Array, stride: int) -> jax.Array:
     return x.reshape(b, h, stride * wq, c)
 
 
+def gathers_taps(kernel: int, c: int) -> bool:
+    """Whether a conv hands the MXU its whole K = Kd^2*C window in one dot.
+
+    True when the window has more than one tap and fits two lane tiles:
+    the taps of a lane-narrow conv (the RGB stem, CNV's ``conv0``) would
+    each fill only C of the MXU's 128-deep contraction.  Wider convs keep
+    one dot per tap, whose C already fills the lanes.
+    """
+    return kernel > 1 and kernel * kernel * c <= 2 * LANE
+
+
 def _kernel(*refs, kernel: int, stride: int, ow: int, wq: int, rt: int,
-            k: int, mode: str, has_thresh: bool, has_scale: bool):
+            k: int, mode: str, gather: bool, has_thresh: bool,
+            has_scale: bool):
     (x_ref, w_ref), t_ref, s_ref, (o_ref,) = split_refs(
         refs, 2, has_thresh, has_scale)
     t = pl.program_id(1)
@@ -153,24 +173,29 @@ def _kernel(*refs, kernel: int, stride: int, ow: int, wq: int, rt: int,
     # rows are touched, each a static slice of the resident image (of the
     # tap's column phase, ``_split_phases``), so no im2col matrix ever
     # exists outside this kernel.
+    def tap(ky, kx):  # (rt*OW, C) int8
+        col = (kx % stride) * wq + kx // stride
+        return jnp.concatenate(
+            [x_ref[0, (t * rt + r) * stride + ky, pl.ds(col, ow), :]
+             for r in range(rt)], axis=0)
+
+    taps = (tap(ky, kx) for ky in range(kernel) for kx in range(kernel))
+    if gather:  # one (rt*OW, K) window in (ky, kx, c) order, one (K, bn) block
+        steps = [(jnp.concatenate(list(taps), axis=1), w_ref[...])]
+    else:  # one (C, bn) weight slice per tap
+        steps = ((a, w_ref[i]) for i, a in enumerate(taps))
     acc = rowsum = colsum = None
-    for ky in range(kernel):
-        for kx in range(kernel):
-            col = (kx % stride) * wq + kx // stride
-            a = jnp.concatenate(
-                [x_ref[0, (t * rt + r) * stride + ky, pl.ds(col, ow), :]
-                 for r in range(rt)], axis=0)  # (rt*OW, C) int8
-            w_tap = w_ref[ky * kernel + kx]  # (C, bn) int8
-            dot = jax.lax.dot_general(
-                a, w_tap, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.int32)
-            acc = dot if acc is None else acc + dot
-            if mode != "standard":
-                rs = jnp.sum(a.astype(jnp.int32), axis=1, keepdims=True)
-                rowsum = rs if rowsum is None else rowsum + rs
-            if mode == "xnor":
-                cs = jnp.sum(w_tap.astype(jnp.int32), axis=0, keepdims=True)
-                colsum = cs if colsum is None else colsum + cs
+    for a, w_blk in steps:
+        dot = jax.lax.dot_general(
+            a, w_blk, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.int32)
+        acc = dot if acc is None else acc + dot
+        if mode != "standard":
+            rs = jnp.sum(a.astype(jnp.int32), axis=1, keepdims=True)
+            rowsum = rs if rowsum is None else rowsum + rs
+        if mode == "xnor":
+            cs = jnp.sum(w_blk.astype(jnp.int32), axis=0, keepdims=True)
+            colsum = cs if colsum is None else colsum + cs
     if mode == "binary":
         acc = 2 * acc - rowsum
     elif mode == "xnor":  # both operands {0,1}-coded +/-1
@@ -204,7 +229,7 @@ def conv_mvu_pallas(
     """out[B, OH*OW, N] = epilogue(SWU(x) . W^T), without materializing SWU(x).
 
     x: (B, H, W, C) integer activations (standard/binary) or {0,1} bits
-       (xnor); int8 runs one MXU pass per tap, a wider dtype one per
+       (xnor); int8 runs one kernel pass, a wider dtype one per
        base-256 digit
     w: (N, K = Kd^2*C) int8 packed in (ky, kx, c) order; binary/xnor rows are
        {0,1}-coded +/-1 (``packing.bipolar_to_bits``)
@@ -243,10 +268,16 @@ def conv_mvu_pallas(
     )
     x_p = _split_phases(x_p, stride)
     hp, wp = x_p.shape[1], x_p.shape[2]
-    # (N, Kd^2*C) -> (Kd^2, C, N): one (C, PE) weight slice per tap
-    w_t = pad_to(w.astype(jnp.int8), 0, bn).reshape(-1, kernel * kernel, c)
-    w_t = jnp.transpose(w_t, (1, 2, 0))
-    np_ = w_t.shape[2]
+    gather = gathers_taps(kernel, c)
+    w_t = pad_to(w.astype(jnp.int8), 0, bn)
+    np_ = w_t.shape[0]
+    if gather:  # (N, K) -> (K, N): the window's features in (ky, kx, c) order
+        w_t = w_t.T
+        w_spec = pl.BlockSpec((k, bn), lambda bi, ti, ni: (0, ni))
+    else:  # (N, Kd^2*C) -> (Kd^2, C, N): one (C, PE) weight slice per tap
+        w_t = jnp.transpose(w_t.reshape(np_, kernel * kernel, c), (1, 2, 0))
+        w_spec = pl.BlockSpec((kernel * kernel, c, bn),
+                              lambda bi, ti, ni: (0, 0, ni))
     epi_specs, epi_ops, out_dtype = epilogue_operands(
         thresholds, out_scale, bn, lambda bi, ti, ni: ni)
     has_thresh, has_scale = thresholds is not None, out_scale is not None
@@ -254,14 +285,13 @@ def conv_mvu_pallas(
     out = pl.pallas_call(
         functools.partial(
             _kernel, kernel=kernel, stride=stride, ow=ow, wq=wp // stride,
-            rt=rt, k=k, mode=mode, has_thresh=has_thresh,
+            rt=rt, k=k, mode=mode, gather=gather, has_thresh=has_thresh,
             has_scale=has_scale,
         ),
         grid=(b, n_tiles, np_ // bn),
         in_specs=[
             pl.BlockSpec((1, hp, wp, c), lambda bi, ti, ni: (bi, 0, 0, 0)),
-            pl.BlockSpec((kernel * kernel, c, bn),
-                         lambda bi, ti, ni: (0, 0, ni)),
+            w_spec,
             *epi_specs,
         ],
         out_specs=pl.BlockSpec((1, rt * ow, bn), lambda bi, ti, ni: (bi, ti, ni)),

@@ -13,6 +13,7 @@ from repro.core.engine import FusedEngine
 from repro.core.folding import Folding
 from repro.core.ir import Graph, Node
 from repro.kernels import ops, packing, ref
+from repro.kernels.swu_mvu import conv_mvu_pallas, gathers_taps
 
 GRID = [(kd, st, pd) for kd in (1, 3, 5) for st in (1, 2) for pd in (0, 1, 2)]
 MODES = ("standard", "binary", "xnor")
@@ -139,6 +140,57 @@ def test_conv_mvu_kernel_threshold_epilogue(mode, n_thresh, table, n):
         assert (want == 0).all()
     else:
         assert want.max() <= n_thresh and want.min() < want.max()
+
+
+@pytest.mark.parametrize("mode,kd,stride,pad,c,dtype,gathered", [
+    pytest.param("standard", 7, 2, 3, 3, jnp.uint8, True, id="stem-uint8"),
+    pytest.param("standard", 7, 1, 0, 3, jnp.int8, True, id="7x7-standard"),
+    pytest.param("binary", 7, 2, 3, 3, jnp.int8, True, id="7x7s2-binary"),
+    pytest.param("xnor", 7, 1, 3, 3, jnp.int8, True, id="7x7-xnor"),
+    pytest.param("standard", 3, 2, 0, 3, jnp.int8, True, id="3x3s2-standard"),
+    pytest.param("binary", 3, 1, 3, 3, jnp.uint8, True, id="3x3-binary-uint8"),
+    pytest.param("xnor", 3, 1, 0, 3, jnp.int8, True, id="conv0-xnor"),
+    pytest.param("xnor", 3, 2, 3, 3, jnp.uint8, True, id="3x3s2-xnor-uint8"),
+    pytest.param("binary", 3, 1, 0, 27, jnp.int8, True, id="c27-binary"),
+    pytest.param("standard", 3, 2, 3, 27, jnp.int8, True, id="c27s2-standard"),
+    pytest.param("xnor", 3, 1, 0, 32, jnp.int8, False, id="c32-xnor"),
+    pytest.param("standard", 3, 1, 3, 32, jnp.uint8, False, id="c32-uint8"),
+])
+def test_gathered_window_matches_reference(mode, kd, stride, pad, c, dtype,
+                                           gathered):
+    """Lane-narrow windows gathered into one dot, and the one-dot-per-tap
+    path just past two lane tiles, == the materialized SWU reference,
+    raw and through thresholds.  uint8 inputs run the base-256 digit path
+    (two kernel passes, thresholds in XLA)."""
+    assert gathers_taps(kd, c) == gathered
+    rng = np.random.default_rng(kd * 1000 + c * 10 + stride + pad)
+    n, k = 20, kd * kd * c
+    hi = 2 if mode == "xnor" else np.iinfo(np.dtype(dtype)).max + 1
+    lo = 0 if dtype == jnp.uint8 or mode == "xnor" else -128
+    x = jnp.asarray(rng.integers(lo, hi, (2, 10, 16, c)), dtype)
+    if mode == "standard":
+        w = jnp.asarray(rng.integers(-128, 128, (n, k)), jnp.int8)
+    else:
+        w = jnp.asarray(rng.integers(0, 2, (n, k)), jnp.int8)
+    kw = dict(kernel=kd, stride=stride, pad=pad, mode=mode)
+    acc = np.asarray(ref.conv_mvu_ref(x, w, **kw))
+    tiles = dict(block_m=32, interpret=True)  # several row tiles an image
+    got = conv_mvu_pallas(x, w, **kw, **tiles)
+    np.testing.assert_array_equal(np.asarray(got), acc)
+    t = jnp.asarray(np.sort(rng.integers(acc.min(), acc.max() + 1, (n, 3)),
+                            axis=1), jnp.int32)
+    got = conv_mvu_pallas(x, w, t, **kw, **tiles)
+    want = ref.conv_mvu_ref(x, w, thresholds=t, **kw)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("kd,c,gathered", [
+    (7, 3, True), (3, 3, True), (3, 64, False), (1, 3, False), (1, 256, False),
+])
+def test_gathers_taps_only_lane_narrow_windows(kd, c, gathered):
+    """A window of more than one tap that fits two lane tiles is gathered;
+    C >= 64 3x3 convs and every 1x1 conv keep one dot per tap."""
+    assert gathers_taps(kd, c) == gathered
 
 
 def test_fuse_swu_collapses_pairs():

@@ -14,6 +14,7 @@ and the range pass that narrows 8-bit and 2-bit streams.
 
 from __future__ import annotations
 
+import json
 import os
 import sys
 
@@ -261,3 +262,37 @@ def test_build_counts_lowered_nodes_per_bit_width():
     counts = {ev["name"]: ev["args"]["value"] for ev in acc.tracer.events()
               if ev["ph"] == "C"}
     assert counts == {"lower.binary.w1a2": 16, "lower.standard.w8a2": 2}
+
+
+def _published(name):
+    from repro.configs import cnv_bnn, nid_mlp
+
+    if name == "resnet50_w1a2":
+        with open(os.path.join(ROOT, "bench", "configs", f"{name}.json")) as f:
+            spec = json.load(f)
+        return resnet.graph(spec, _draw_only(spec)), dict(
+            mode="binary", weight_bits=1, act_bits=2)
+    if name == "cnv_full":
+        return cnv_bnn.build_graph(cnv_bnn.FULL), dict(
+            mode="xnor", weight_bits=1, act_bits=1)
+    return nid_mlp.build_graph(0), dict(
+        mode="standard", weight_bits=nid_mlp.WEIGHT_BITS,
+        act_bits=nid_mlp.INPUT_BITS)
+
+
+@pytest.mark.parametrize("name,gathered", [
+    ("resnet50_w1a2", 1), ("cnv_full", 1), ("nid_mlp", None),
+])
+def test_build_counts_gathered_conv_windows(name, gathered):
+    """At published widths the build counts the convs whose whole window
+    the line buffer hands the MXU in one dot: the ResNet-50 stem and CNV's
+    ``conv0`` (C = 3); none in NID, which has no conv.  The build stops at
+    ``fuse_swu``, where a conv's kernel and C are first known together."""
+    g, bits = _published(name)
+    steps = ["validate", "lower", "finalize", "fold", "fuse_epilogues",
+             "fuse_swu"]
+    acc = build(g, target="engine", steps=steps, verify="off",
+                telemetry=True, **bits)
+    counts = {ev["name"]: ev["args"]["value"] for ev in acc.tracer.events()
+              if ev["ph"] == "C"}
+    assert counts.get("lower.conv.gathered_taps") == gathered

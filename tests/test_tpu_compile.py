@@ -7,8 +7,8 @@ reshapes Mosaic cannot lay out, more VMEM than a kernel may use.  Interpret
 mode (every other kernel test) cannot see any of that.  Shapes are the real
 ones: the NID-MLP layers with the tiles its build picks, 512x512 dense
 layers at CNV's width, the first two CNV FULL conv layers (and a strided
-conv), ResNet-50 W1A2's stem, strided convs and residual join, and the
-whole NID engine step.
+conv, and ``conv0`` one image per call), ResNet-50 W1A2's stem, strided
+convs and residual join, and the whole NID engine step.
 
 The topology is described inside a fixture, never on import: only one
 process at a time may load the TPU library, and every test worker imports
@@ -130,13 +130,17 @@ def test_conv_layers_compile(one_chip, h, c, n, mode, stride, pad):
     (224, 3, 64, 7, 2, 3, "standard", jnp.uint8, 2),  # the 8-bit RGB stem
     (56, 128, 128, 3, 2, 1, "binary", jnp.int8, 1),  # stage 2's strided 3x3
     (56, 256, 512, 1, 2, 0, "binary", jnp.int8, 1),  # its 1x1 projection
+    (32, 3, 64, 3, 1, 0, "xnor", jnp.int8, 1),  # CNV FULL conv0
 ])
 def test_resnet_convs_compile(one_chip, h, c, n, kernel, stride, pad, mode,
                               dtype, passes):
     """ResNet-50 W1A2's strided and padded convs at published shapes, one
     image per call as the engine runs them: the stem over C = 3 in two
     digit passes, and wide strided maps whose taps read one column phase
-    each (the chip strides only 32-bit rows of 128 lanes)."""
+    each (the chip strides only 32-bit rows of 128 lanes).  The stem and
+    CNV's ``conv0`` gather their lane-narrow windows into one dot; the
+    kernel keeps its name and pass count, which the roofline readers
+    count by."""
     fn = lambda x, w, t: conv_mvu_pallas(x, w, t, kernel=kernel, stride=stride,
                                          pad=pad, mode=mode,
                                          block_n=min(n, 128), interpret=False)
